@@ -73,6 +73,8 @@ def main(rows):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     out = []
     main(out)
     for name, us, derived in out:

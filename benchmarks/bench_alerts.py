@@ -106,6 +106,8 @@ def main(rows, *, tiny: bool = False):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     out: list = []
     main(out, tiny="--tiny" in sys.argv)
     for name, us, derived in out:

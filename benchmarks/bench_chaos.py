@@ -112,6 +112,8 @@ def main(rows: list, *, soak: bool = False) -> list:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     out: list = []
     main(out, soak="--soak" in sys.argv)
     for name, us, derived in out:

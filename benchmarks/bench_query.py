@@ -251,6 +251,8 @@ def main(rows, *, smoke: bool = False):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     out: list = []
     main(out, smoke="--smoke" in sys.argv or "--tiny" in sys.argv)
     for name, us, derived in out:

@@ -343,6 +343,8 @@ def main(rows, *, smoke: bool = False):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if "--offload-roundtrip" in sys.argv:     # CI smoke: tiering only
         res = offload_roundtrip(2_000)
         print("offload_roundtrip OK "

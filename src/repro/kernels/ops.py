@@ -1,8 +1,10 @@
 """Jitted public wrappers for the Pallas kernels.
 
-``interpret`` defaults to True off-TPU (this container is CPU-only; the
-kernels TARGET TPU and are validated by executing their bodies in
-interpret mode).  On a TPU backend the same calls compile natively.
+The kernels target the TPU.  ``interpret=None`` resolves from the JAX
+backend: native compilation on ``tpu``, the Pallas interpreter on
+``cpu`` (where the test suite validates the kernel bodies), and an error
+on any other backend, so a machine whose TPU failed to initialise never
+falls back to the interpreter in silence.
 """
 from __future__ import annotations
 
@@ -18,7 +20,15 @@ from repro.kernels.window_reduce import window_reduce_fwd
 
 
 def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas kernels compile natively only on tpu, and interpret mode "
+        f"is chosen only on cpu; the JAX backend here is {backend!r}. "
+        f"Pass interpret=True to run the interpreter anyway.")
 
 
 def flash_attention(q, k, v, *, causal=True, window=0, block_q=256,

@@ -17,8 +17,10 @@ and reduces along events; count/sum/sumsq could equally ride the MXU as a
 one-hot matmul, but max needs the compare anyway so everything stays on
 the VPU.
 
-Interpret mode on CPU (how CI validates parity vs ``ref.window_reduce_ref``
-to 1e-5); the same call compiles natively on TPU.
+The kernel compiles natively for the TPU (``tests/test_tpu_compile.py``
+compiles it for a v5e at the batch sizes the pipeline launches, and
+``chip_smoke.py`` runs it on the chip).  On the CPU the tests run it in
+interpret mode against ``ref.window_reduce_ref``.
 """
 from __future__ import annotations
 

@@ -11,14 +11,22 @@ from typing import Optional, Tuple
 
 import jax
 import numpy as np
+from jax.sharding import AxisType
 
 from repro.config import MeshConfig, MULTI_POD, SINGLE_POD
+
+
+def _auto_mesh(shape, axes):
+    # jax.make_mesh defaults to Explicit axes, which reject the
+    # with_sharding_constraint / gather rules the model stack is written
+    # against; every mesh here is Auto
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def mesh_config(*, multi_pod: bool = False) -> MeshConfig:
@@ -26,7 +34,7 @@ def mesh_config(*, multi_pod: bool = False) -> MeshConfig:
 
 
 def make_mesh_from_config(cfg: MeshConfig):
-    return jax.make_mesh(cfg.shape, cfg.axes)
+    return _auto_mesh(cfg.shape, cfg.axes)
 
 
 def make_local_mesh(model: int = 1, data: Optional[int] = None,
@@ -45,7 +53,7 @@ def make_local_mesh(model: int = 1, data: Optional[int] = None,
         shape, axes = (data, model), ("data", "model")
     used = int(np.prod(shape))
     assert used <= n, f"mesh {shape} needs {used} devices, have {n}"
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def local_mesh_config(mesh) -> MeshConfig:

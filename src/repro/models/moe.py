@@ -155,10 +155,7 @@ def moe_apply_xla(
 def moe_apply_shard_map(
     p: Dict[str, jax.Array], x: jax.Array, cfg: ModelConfig, mesh
 ) -> Tuple[jax.Array, jax.Array]:
-    try:
-        from jax import shard_map
-    except ImportError:                 # jax < 0.6 keeps it in experimental
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     m = cfg.moe
@@ -223,17 +220,13 @@ def moe_apply_shard_map(
         w_specs = (P(None, "data", "model"), P(None, "data", "model"),
                    P(None, "model", "data"))
 
-    import inspect
-    # the replication checker flag was renamed check_rep -> check_vma;
-    # disable it either way (the psum/a2a mix confuses it)
-    check_kw = ("check_vma" if "check_vma"
-                in inspect.signature(shard_map).parameters else "check_rep")
+    # the replication checker is off: the psum/a2a mix confuses it
     fn = shard_map(
         local,
         mesh=mesh,
         in_specs=(P(bd, seq_ax, None), P(None, None)) + w_specs,
         out_specs=(P(bd, seq_ax, None), P()),
-        **{check_kw: False},
+        check_vma=False,
     )
     return fn(x, p["router"], p["w_gate"], p["w_up"], p["w_down"])
 

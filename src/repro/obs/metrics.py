@@ -212,7 +212,8 @@ class Histogram(_Instrument):
             for i in idxs:
                 counts[i] += 1
             s.count += len(values)
-            s.sum += sum(values)
+            for v in values:          # one add per value, as observe() does
+                s.sum += float(v)
             lo, hi = min(values), max(values)
             if lo < s.min:
                 s.min = float(lo)

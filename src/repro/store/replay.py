@@ -183,8 +183,11 @@ class ReplayEngine:
                else self.tracer.span("replay.events",
                                      attrs={"events": len(events)}))
         with ctx:
+            # with_min: replayed windows reach the query plane's hot store
+            # and the rules as whole aggregates; a window made only of
+            # replayed events would otherwise carry min=+inf
             aggs = reduce_events(events, spec, interpret=self.interpret,
-                                 profiler=self.profiler)
+                                 profiler=self.profiler, with_min=True)
             wm = watermark if watermark is not None \
                 else self.analytics.operator.watermark
             for a in aggs:
@@ -221,7 +224,7 @@ class ReplayEngine:
             aggs = reduce_columns(lanes.ts, lanes.key_codes, lanes.values,
                                   lanes.key_vocab, spec,
                                   interpret=self.interpret,
-                                  profiler=self.profiler)
+                                  profiler=self.profiler, with_min=True)
             wm = watermark if watermark is not None \
                 else self.analytics.operator.watermark
             for a in aggs:

@@ -20,9 +20,6 @@ def run8(body: str, timeout=420) -> str:
         import jax.numpy as jnp
         import numpy as np
         assert len(jax.devices()) == 8
-        if not hasattr(jax, "shard_map"):   # jax < 0.6 compat
-            from jax.experimental.shard_map import shard_map as _sm
-            jax.shard_map = _sm
     """) + textwrap.dedent(body)
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(_ROOT, "src")

@@ -548,6 +548,27 @@ def test_replay_stamped_ahead_of_live_does_not_silence_rate_rule():
     assert len(surges) == 1 and surges[0].window_start == 60.0
 
 
+@pytest.mark.parametrize("route", ["events", "columns"])
+def test_replayed_windows_carry_their_min(route):
+    """A window made only of replayed events reaches the query plane's
+    hot store and the rules as a whole aggregate: its min is the events'
+    min, not the +inf of an empty accumulator."""
+    from repro.store.columnar.log import Lanes
+
+    stage = AnalyticsStage(WindowSpec(size_s=60.0), [])
+    eng = ReplayEngine(analytics=stage, interpret=True)
+    events = [("news", 5.0, 3.0), ("news", 7.0, 2.0), ("news", 65.0, 4.0)]
+    if route == "events":
+        aggs, _ = eng.replay_events(events, watermark=0.0)
+    else:
+        aggs, _ = eng.replay_columns(Lanes(
+            ts=np.array([t for _, t, _ in events]),
+            key_codes=np.zeros(3, np.int64), key_vocab=["news"],
+            values=np.array([v for _, _, v in events])), watermark=0.0)
+    assert [(a.window_start, a.min, a.max) for a in aggs] == [
+        (0.0, 2.0, 3.0), (60.0, 4.0, 4.0)]
+
+
 def test_log_truncate_crash_between_manifest_and_unlink(tmp_path):
     """truncate() rewrites the manifest BEFORE unlinking: simulate the
     crash window by restoring a doomed segment file after truncation —
