@@ -1,0 +1,9 @@
+"""Share of the replay window's wall time in the replay engine's
+``kernel.fetch`` sub-stage (``np.asarray``, each launch's copy to the
+host), from the program's ring of recent passes
+(``bench/device/program_spans.py``)."""
+from bench.device.program_spans import replay_share
+
+
+def read(record):
+    return replay_share(record, "kernel.fetch")

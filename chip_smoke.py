@@ -64,8 +64,6 @@ import os
 import sys
 import tempfile
 import time
-from collections import Counter
-from contextlib import contextmanager
 from pathlib import Path
 from typing import Dict, Hashable, Iterable, List, Sequence, Tuple
 
@@ -83,7 +81,7 @@ KERNEL_SEGMENTS = 4096
 SLIDING_SIZE_S = 300.0
 SLIDING_SLIDE_S = 60.0
 SEED = 0
-ROUTES = ("late_drain", "cold_query", "log_replay", "direct")
+ROUTES = ("drain", "query", "replay", "direct")   # repro.obs.launches
 
 _U = 2.0 ** -24             # float32 unit roundoff
 _U64 = 2.0 ** -53           # float64 unit roundoff
@@ -218,46 +216,25 @@ def _agg_lanes(aggs) -> Dict[Slot, Lanes4]:
 
 # ---- instrumentation -------------------------------------------------------
 
-class LaunchRecorder:
-    """Records every ``window_reduce_fwd`` launch that ``ops.window_reduce``
-    makes, under the route the script is driving, with the ``interpret``
-    value the kernel was called with."""
+def launch_counts() -> Dict[str, Dict[str, int]]:
+    """The program's own ``window_reduce`` launch counters, per route
+    (``repro.obs.launches``: replay, drain, query, direct)."""
+    from repro.obs.launches import kernel_launches
 
-    def __init__(self):
-        self.route = "unrouted"
-        self.launches: List[Tuple[str, int, int, object]] = []
+    return kernel_launches().get("window_reduce", {})
 
-    @contextmanager
-    def installed(self):
-        from repro.kernels import ops
 
-        inner = ops.window_reduce_fwd
-
-        def recorded(values, seg_ids, *, num_segments, interpret, **kw):
-            self.launches.append((self.route, int(values.shape[0]),
-                                  int(num_segments), interpret))
-            return inner(values, seg_ids, num_segments=num_segments,
-                         interpret=interpret, **kw)
-
-        ops.window_reduce_fwd = recorded
-        try:
-            yield self
-        finally:
-            ops.window_reduce_fwd = inner
-
-    @contextmanager
-    def route_as(self, route: str):
-        prev, self.route = self.route, route
-        try:
-            yield
-        finally:
-            self.route = prev
-
-    def per_route(self) -> Dict[str, int]:
-        return dict(Counter(r for r, _, _, _ in self.launches))
-
-    def shapes(self) -> int:
-        return len({(n, s) for _, n, s, _ in self.launches})
+def launches_since(before: Dict[str, Dict[str, int]]
+                   ) -> Dict[str, Dict[str, int]]:
+    """Per route, the counters' growth since ``before`` (routes that did
+    not launch are left out)."""
+    out = {}
+    for route, now in launch_counts().items():
+        was = before.get(route, {})
+        delta = {k: v - was.get(k, 0) for k, v in now.items()}
+        if delta["launches"]:
+            out[route] = delta
+    return out
 
 
 class CompileMonitor:
@@ -320,7 +297,7 @@ def build_pipeline(store_dir: str, *, num_sources: int = NUM_SOURCES,
                             analytics_rules=rules)
 
 
-def run_with_late_drains(p, rec: LaunchRecorder, *,
+def run_with_late_drains(p, *,
                          virtual_s: float = VIRTUAL_S,
                          chunk_s: float = CHUNK_S, **run_kw) -> dict:
     """(a): run the pipeline in chunks; each ``run_for`` ends in
@@ -343,20 +320,18 @@ def run_with_late_drains(p, rec: LaunchRecorder, *,
     replay.replay_events = recording
     worst, drains, late_total = [], 0, 0
     try:
-        with rec.route_as("late_drain"):
-            while p.now < virtual_s:
-                p.run_for(min(chunk_s, virtual_s - p.now), dt=DT_S, **run_kw)
-                got = [a for aggs in drained for a in aggs]
-                ref = reference_reduce(
-                    [m["key"] for m in late],
-                    tumbling_starts([m["event_time"] for m in late],
-                                    WINDOW_S),
-                    np.array([m["value"] for m in late], np.float64))
-                worst.append(compare("late_drain", _agg_lanes(got), ref))
-                drains += len(drained)
-                late_total += len(late)
-                late.clear()
-                drained.clear()
+        while p.now < virtual_s:
+            p.run_for(min(chunk_s, virtual_s - p.now), dt=DT_S, **run_kw)
+            got = [a for aggs in drained for a in aggs]
+            ref = reference_reduce(
+                [m["key"] for m in late],
+                tumbling_starts([m["event_time"] for m in late], WINDOW_S),
+                np.array([m["value"] for m in late], np.float64))
+            worst.append(compare("late_drain", _agg_lanes(got), ref))
+            drains += len(drained)
+            late_total += len(late)
+            late.clear()
+            drained.clear()
     finally:
         del replay.replay_events
     _check(late_total > 0, "no event reached the late-event journal")
@@ -364,7 +339,7 @@ def run_with_late_drains(p, rec: LaunchRecorder, *,
             "late_events": late_total, **_merge_worst(worst)}
 
 
-def check_cold_query(p, rec: LaunchRecorder) -> dict:
+def check_cold_query(p) -> dict:
     """(c): ``AggQuery(agg="min")`` over ``[0, floor)`` per channel, against
     a pure-Python fold of every document in the event log."""
     from repro.query import AggQuery
@@ -385,33 +360,31 @@ def check_cold_query(p, rec: LaunchRecorder) -> dict:
         cur[1] = min(cur[1], v)
     scans0 = p.query.status()["cold_scans"]
     points = 0
-    with rec.route_as("cold_query"):
-        for channel in p.channels():
-            res = p.query.query(AggQuery(channel=channel, start=0.0,
-                                         end=floor, agg="min"),
-                                use_cache=False)
-            got = {(pt["key"], pt["start"]): [pt["count"], pt["value"]]
-                   for pt in res.points}
-            want = {k: v for k, v in ref.items() if k[0] == channel}
-            diff = sorted(k for k in set(got) | set(want)
-                          if got.get(k) != want.get(k))
-            _check(not diff,
-                   f"cold_query {channel}: {len(diff)} of {len(want)} "
-                   f"windows differ from the log fold, e.g. "
-                   f"{[(k, got.get(k), want.get(k)) for k in diff[:3]]}")
-            points += len(got)
+    for channel in p.channels():
+        res = p.query.query(AggQuery(channel=channel, start=0.0,
+                                     end=floor, agg="min"),
+                            use_cache=False)
+        got = {(pt["key"], pt["start"]): [pt["count"], pt["value"]]
+               for pt in res.points}
+        want = {k: v for k, v in ref.items() if k[0] == channel}
+        diff = sorted(k for k in set(got) | set(want)
+                      if got.get(k) != want.get(k))
+        _check(not diff,
+               f"cold_query {channel}: {len(diff)} of {len(want)} "
+               f"windows differ from the log fold, e.g. "
+               f"{[(k, got.get(k), want.get(k)) for k in diff[:3]]}")
+        points += len(got)
     scans = p.query.status()["cold_scans"] - scans0
     _check(scans > 0, "no query reached the cold path")
     return {"phase": "cold_query", "floor": floor, "windows": points,
             "cold_scans": scans}
 
 
-def check_log_replay(p, rec: LaunchRecorder) -> dict:
+def check_log_replay(p) -> dict:
     """(b): ``replay_columns`` over the whole sealed columnar log."""
     lanes = p.store.log.scan_lanes(include_tail=False)
     _check(lanes.count > 0, "no sealed columnar segment to replay")
-    with rec.route_as("log_replay"):
-        aggs, fired = p.store.replay.replay_columns(lanes, watermark=p.now)
+    aggs, fired = p.store.replay.replay_columns(lanes, watermark=p.now)
     keys = [lanes.key_vocab[c] for c in lanes.key_codes]
     ref = reference_reduce(keys, tumbling_starts(lanes.ts, WINDOW_S),
                            lanes.values)
@@ -420,7 +393,7 @@ def check_log_replay(p, rec: LaunchRecorder) -> dict:
             **compare("log_replay", _agg_lanes(aggs), ref)}
 
 
-def check_direct(rec: LaunchRecorder, lanes, *,
+def check_direct(lanes, *,
                  n_events: int = KERNEL_EVENTS,
                  n_segments: int = KERNEL_SEGMENTS,
                  seed: int = SEED) -> dict:
@@ -433,8 +406,7 @@ def check_direct(rec: LaunchRecorder, lanes, *,
     rng = np.random.default_rng(seed)
     vals = rng.normal(size=n_events).astype(np.float32)
     segs = rng.integers(0, n_segments, size=n_events).astype(np.int32)
-    with rec.route_as("direct"):
-        out = np.asarray(ops.window_reduce(vals, segs, n_segments))
+    out = np.asarray(ops.window_reduce(vals, segs, n_segments))
     _check(out.shape == (n_segments, 4), f"direct: shape {out.shape}")
     _check(bool(np.isfinite(out[:, :3]).all()), "direct: non-finite lanes")
     ref = reference_reduce(segs.tolist(), np.zeros(n_events), vals)
@@ -453,8 +425,7 @@ def check_direct(rec: LaunchRecorder, lanes, *,
                       slide_s=SLIDING_SLIDE_S)
     packed, seg_ids, slots = pack_columns(lanes.ts, lanes.key_codes,
                                           svals, spec)
-    with rec.route_as("direct"):
-        sout = np.asarray(ops.window_reduce(packed, seg_ids, len(slots)))
+    sout = np.asarray(ops.window_reduce(packed, seg_ids, len(slots)))
     got = {(code, start): tuple(float(x) for x in sout[i])
            for i, (code, start, _end) in enumerate(slots)}
     idx, starts = sliding_expand(lanes.ts, SLIDING_SIZE_S, SLIDING_SLIDE_S)
@@ -496,19 +467,18 @@ def main() -> int:
     cache_dir = enable_compile_cache()
     comp = CompileMonitor()
     comp.install()
-    rec = LaunchRecorder()
+    launches0 = launch_counts()
     walls: Dict[str, float] = {}
     summary: Dict[str, object] = {
         "device_kind": dev.device_kind, "device_count": len(devices),
         "compile_cache_dir": cache_dir, "num_sources": NUM_SOURCES,
         "virtual_s": VIRTUAL_S}
     worst = []
-    with tempfile.TemporaryDirectory(prefix="chip_smoke_") as store_dir, \
-            rec.installed():
+    with tempfile.TemporaryDirectory(prefix="chip_smoke_") as store_dir:
         p = build_pipeline(store_dir)
         try:
             t0 = time.perf_counter()
-            res = run_with_late_drains(p, rec)
+            res = run_with_late_drains(p)
             walls["late_drain"] = time.perf_counter() - t0
             _emit(res)
             worst.append(res)
@@ -519,31 +489,34 @@ def main() -> int:
             for name, fn in (("cold_query", check_cold_query),
                              ("log_replay", check_log_replay)):
                 t0 = time.perf_counter()
-                res = fn(p, rec)
+                res = fn(p)
                 walls[name] = time.perf_counter() - t0
                 _emit(res)
                 if "sum_abs_err" in res:
                     worst.append(res)
             t0 = time.perf_counter()
-            res = check_direct(rec, p.store.log.scan_lanes())
+            res = check_direct(p.store.log.scan_lanes())
             walls["direct"] = time.perf_counter() - t0
             _emit(res)
             worst.append(res)
         finally:
             p.close()
-    per_route = rec.per_route()
+    counts = launches_since(launches0)
+    per_route = {r: c["launches"] for r, c in counts.items()}
     for route in ROUTES:
         _check(per_route.get(route, 0) > 0,
                f"route {route} launched no kernel")
-    interp = sorted({repr(i) for _, _, _, i in rec.launches})
-    _check(interp == ["False"],
-           f"kernel launches ran with interpret={interp}, not natively")
+    interpreted = sum(c["interpreted"] for c in counts.values())
+    _check(interpreted == 0,
+           f"{interpreted} kernel launches ran in interpret mode, not "
+           f"natively")
     _check(lowered_is_native(), "lowered window_reduce_fwd holds no "
            "tpu_custom_call")
     stats = dev.memory_stats() or {}
     summary.update(
-        launches_per_route=per_route, launch_shapes=rec.shapes(),
-        interpret_values=interp, native_lowering=True,
+        launches_per_route=per_route,
+        launch_shapes=sum(c["new_shapes"] for c in counts.values()),
+        interpreted_launches=interpreted, native_lowering=True,
         wall_s=walls, worst=_merge_worst(worst),
         peak_bytes_in_use=stats.get("peak_bytes_in_use"),
         compilations=comp.snapshot())

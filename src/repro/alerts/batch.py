@@ -46,7 +46,7 @@ def pack_events(events: Sequence[Event], spec: WindowSpec):
 
 
 def pack_columns(ts: np.ndarray, key_codes: np.ndarray,
-                 values: np.ndarray, spec: WindowSpec):
+                 values: np.ndarray, spec: WindowSpec, *, profiler=None):
     """Vectorized ``pack_events`` over COLUMN arrays (the columnar
     store's ``scan_lanes`` output): no per-event Python at all.
 
@@ -55,75 +55,120 @@ def pack_columns(ts: np.ndarray, key_codes: np.ndarray,
     exact float arithmetic (tumbling: one floor-multiply; sliding: the
     same repeated subtraction, vectorized per step) so slots from the
     two packers are bit-identical — the hot/cold dedup in the query
-    plane depends on it."""
+    plane depends on it.
+
+    ``profiler`` times the three steps as sub-stages of ``pack_events``:
+    ``pack_events.assign`` (window assignment, expansion and the column
+    stack), ``pack_events.unique`` (the (start, key) ``np.unique`` and
+    its inverse) and ``pack_events.slots`` (the Python slot list)."""
     if spec.kind == SESSION:
         raise ValueError("session windows have no static slot layout; "
                          "use WindowOperator")
-    ts = np.asarray(ts, np.float64)
-    codes = np.asarray(key_codes, np.int64)
-    vals = np.asarray(values, np.float64)
-    if ts.size == 0:
-        return (np.empty(0, np.float32), np.empty(0, np.int32), [])
-    if spec.kind == TUMBLING:
-        estarts = np.floor(ts / spec.size_s) * spec.size_s
-        ecodes, evals = codes, vals
-    else:                                 # SLIDING
-        slide = float(spec.slide_s)
-        cur = np.floor(ts / slide) * slide
-        lower = ts - spec.size_s
-        parts_s: List[np.ndarray] = []
-        parts_c: List[np.ndarray] = []
-        parts_v: List[np.ndarray] = []
-        while True:
-            m = cur > lower
-            if not m.any():
-                break
-            parts_s.append(cur[m])
-            parts_c.append(codes[m])
-            parts_v.append(vals[m])
-            cur = cur - slide
-        estarts = np.concatenate(parts_s)
-        ecodes = np.concatenate(parts_c)
-        evals = np.concatenate(parts_v)
-    # one (key, start) slot per distinct pair; codes fit float64 exactly
-    combo = np.column_stack([estarts, ecodes.astype(np.float64)])
-    uniq, inv = np.unique(combo, axis=0, return_inverse=True)
-    slots = [(int(c), float(s), float(s) + spec.size_s)
-             for s, c in uniq]
-    return (evals.astype(np.float32), inv.astype(np.int32).ravel(), slots)
+    stage = _stage_of(profiler)
+    with stage("pack_events.assign"):
+        ts = np.asarray(ts, np.float64)
+        codes = np.asarray(key_codes, np.int64)
+        vals = np.asarray(values, np.float64)
+        if ts.size == 0:
+            return (np.empty(0, np.float32), np.empty(0, np.int32), [])
+        if spec.kind == TUMBLING:
+            estarts = np.floor(ts / spec.size_s) * spec.size_s
+            ecodes, evals = codes, vals
+        else:                             # SLIDING
+            slide = float(spec.slide_s)
+            cur = np.floor(ts / slide) * slide
+            lower = ts - spec.size_s
+            parts_s: List[np.ndarray] = []
+            parts_c: List[np.ndarray] = []
+            parts_v: List[np.ndarray] = []
+            while True:
+                m = cur > lower
+                if not m.any():
+                    break
+                parts_s.append(cur[m])
+                parts_c.append(codes[m])
+                parts_v.append(vals[m])
+                cur = cur - slide
+            estarts = np.concatenate(parts_s)
+            ecodes = np.concatenate(parts_c)
+            evals = np.concatenate(parts_v)
+        # one (key, start) slot per distinct pair; codes fit float64 exactly
+        combo = np.column_stack([estarts, ecodes.astype(np.float64)])
+        packed = evals.astype(np.float32)
+    with stage("pack_events.unique"):
+        uniq, inv = np.unique(combo, axis=0, return_inverse=True)
+        seg_ids = inv.astype(np.int32).ravel()
+    with stage("pack_events.slots"):
+        slots = [(int(c), float(s), float(s) + spec.size_s)
+                 for s, c in uniq]
+    return (packed, seg_ids, slots)
 
 
 def reduce_columns(ts: np.ndarray, key_codes: np.ndarray,
                    values: np.ndarray, key_vocab: Sequence[str],
                    spec: WindowSpec, *, interpret=None, profiler=None,
-                   with_min: bool = False) -> List[WindowAggregate]:
+                   with_min: bool = False,
+                   route: str = "direct") -> List[WindowAggregate]:
     """``reduce_events`` fed by column arrays: pack_columns ->
     window_reduce -> WindowAggregates, with the same profiler stage
     names so the replay breakdown stays comparable.  Per-record Python
     appears only in the final per-SLOT unpack (S slots, not N events)."""
-    from repro.kernels import ops   # lazy: keep host path jax-free
-
-    stage = profiler.stage if profiler is not None else (
-        lambda name: _NULL_STAGE)
+    stage = _stage_of(profiler)
     with stage("pack_events"):
         packed_vals, seg_ids, slots = pack_columns(
-            ts, key_codes, values, spec)
+            ts, key_codes, values, spec, profiler=profiler)
+    return _reduce_packed(packed_vals, seg_ids, slots, key_vocab,
+                          stage=stage, interpret=interpret,
+                          with_min=with_min, route=route)
+
+
+def reduce_events(events: Sequence[Event], spec: WindowSpec, *,
+                  interpret=None, profiler=None, with_min: bool = False,
+                  route: str = "direct") -> List[WindowAggregate]:
+    """One kernel launch -> WindowAggregates for every touched slot.
+
+    ``profiler`` (a ``repro.obs.StageProfiler``) itemizes the chain into
+    pack_events / kernel / unpack stages, the kernel stage into
+    ``kernel.dispatch`` / ``kernel.wait`` / ``kernel.fetch`` per launch.
+    ``route`` names the caller for the kernel's launch counters.
+
+    ``with_min=True`` adds a second launch over the negated values —
+    ``min(v) = -max(-v)`` — so per-slot minima come out of the same
+    4-lane kernel without changing its pinned (S, 4) output shape.  The
+    query plane (repro.query) needs min; the rule engine's live path
+    already tracks it incrementally."""
+    stage = _stage_of(profiler)
+    with stage("pack_events"):
+        values, seg_ids, slots = pack_events(events, spec)
+    return _reduce_packed(values, seg_ids, slots, None, stage=stage,
+                          interpret=interpret, with_min=with_min,
+                          route=route)
+
+
+def _reduce_packed(values, seg_ids, slots, key_vocab, *, stage, interpret,
+                   with_min: bool, route: str) -> List[WindowAggregate]:
+    """The kernel and unpack stages both packers share.  A slot's key is
+    ``key_vocab[key]`` when a vocabulary is given (column packs carry
+    key codes), else the key itself."""
     if not slots:
         return []
     with stage("kernel"):
-        lanes = np.asarray(ops.window_reduce(
-            packed_vals, seg_ids, len(slots), interpret=interpret))
+        # the max lane is dispatched, waited on and fetched before the
+        # min lane is dispatched
+        lanes = _kernel_lane(values, seg_ids, len(slots), stage=stage,
+                             interpret=interpret, route=route)
         mins = None
         if with_min:
-            neg = np.asarray(ops.window_reduce(
-                -packed_vals, seg_ids, len(slots), interpret=interpret))
-            mins = -neg[:, 3]
+            mins = -_kernel_lane(values, seg_ids, len(slots), stage=stage,
+                                 interpret=interpret, route=route,
+                                 negate=True)[:, 3]
     with stage("unpack"):
         out: List[WindowAggregate] = []
-        for sid, (code, start, end) in enumerate(slots):
+        for sid, (key, start, end) in enumerate(slots):
             cnt, sm, sq, mx = lanes[sid]
             agg = WindowAggregate(
-                key=key_vocab[code], window_start=start, window_end=end,
+                key=key if key_vocab is None else key_vocab[key],
+                window_start=start, window_end=end,
                 count=int(round(cnt)), sum=float(sm), sumsq=float(sq),
                 max=float(mx))
             if mins is not None:
@@ -131,6 +176,22 @@ def reduce_columns(ts: np.ndarray, key_codes: np.ndarray,
             out.append(agg)
         out.sort(key=lambda a: (a.window_end, a.key))
     return out
+
+
+def _kernel_lane(values, seg_ids, num_slots: int, *, stage, interpret,
+                 route: str, negate: bool = False) -> np.ndarray:
+    """One ``window_reduce`` launch, timed as ``kernel.dispatch`` (until
+    the call returns), ``kernel.wait`` (until the device is done) and
+    ``kernel.fetch`` (the copy to the host)."""
+    from repro.kernels import ops   # lazy: keep host path jax-free
+
+    with stage("kernel.dispatch"):
+        out = ops.window_reduce(-values if negate else values, seg_ids,
+                                num_slots, interpret=interpret, route=route)
+    with stage("kernel.wait"):
+        out.block_until_ready()
+    with stage("kernel.fetch"):
+        return np.asarray(out)
 
 
 class _NullStage:
@@ -144,46 +205,9 @@ class _NullStage:
 _NULL_STAGE = _NullStage()
 
 
-def reduce_events(events: Sequence[Event], spec: WindowSpec, *,
-                  interpret=None, profiler=None,
-                  with_min: bool = False) -> List[WindowAggregate]:
-    """One kernel launch -> WindowAggregates for every touched slot.
+def _null_stage(name: str) -> _NullStage:
+    return _NULL_STAGE
 
-    ``profiler`` (a ``repro.obs.StageProfiler``) itemizes the chain into
-    pack_events / kernel / unpack stages — the breakdown ROADMAP item 1
-    (the replay-vs-live gap) needs.
 
-    ``with_min=True`` adds a second launch over the negated values —
-    ``min(v) = -max(-v)`` — so per-slot minima come out of the same
-    4-lane kernel without changing its pinned (S, 4) output shape.  The
-    query plane (repro.query) needs min; the rule engine's live path
-    already tracks it incrementally."""
-    from repro.kernels import ops   # lazy: keep host path jax-free
-
-    stage = profiler.stage if profiler is not None else (
-        lambda name: _NULL_STAGE)
-    with stage("pack_events"):
-        values, seg_ids, slots = pack_events(events, spec)
-    if not slots:
-        return []
-    with stage("kernel"):
-        lanes = np.asarray(ops.window_reduce(
-            values, seg_ids, len(slots), interpret=interpret))
-        mins = None
-        if with_min:
-            neg = np.asarray(ops.window_reduce(
-                -values, seg_ids, len(slots), interpret=interpret))
-            mins = -neg[:, 3]
-    with stage("unpack"):
-        out: List[WindowAggregate] = []
-        for sid, (key, start, end) in enumerate(slots):
-            cnt, sm, sq, mx = lanes[sid]
-            agg = WindowAggregate(
-                key=key, window_start=start, window_end=end,
-                count=int(round(cnt)), sum=float(sm), sumsq=float(sq),
-                max=float(mx))
-            if mins is not None:
-                agg.min = float(mins[sid])
-            out.append(agg)
-        out.sort(key=lambda a: (a.window_end, a.key))
-    return out
+def _stage_of(profiler):
+    return profiler.stage if profiler is not None else _null_stage

@@ -67,7 +67,7 @@ from repro.core.scheduler import DEFAULT_CHANNELS, ChannelDistributor, Scheduler
 from repro.core.sinks import IndexSink
 from repro.core.sources import NOT_MODIFIED, SourceSimulator
 from repro.delivery import BatchingSink, FanOutSink, RetryingSink, as_sink
-from repro.obs import LatencySink, Observability, TracingSink
+from repro.obs import LatencySink, Observability, TracingSink, kernel_launches
 
 # repro.ingest imports repro.core.registry (which runs this package's
 # __init__) — import it lazily to keep `import repro.ingest` first legal
@@ -1191,13 +1191,15 @@ class AlertMixPipeline:
                 g("store_cold_segments",
                   "sealed segments currently offloaded").set(
                     col["cold_segments"])
-            # replay-chain breakdown (StageProfiler): the ROADMAP item-1
-            # gap — which stage eats the batch-replay time — visible in
-            # every scrape, not just replay_status()["profile"]
+            # replay-chain breakdown (StageProfiler): which stage eats
+            # the batch-replay time, visible in every scrape, not just
+            # replay_status()["profile"]; the shares are of the top-level
+            # stages, so they add up to 1
             for stage, ps in self.store.replay.profiler.snapshot().items():
-                g("replay_stage_share",
-                  "fraction of profiled replay wall-clock per stage").set(
-                    ps["share"], stage=stage)
+                if "." not in stage:
+                    g("replay_stage_share",
+                      "fraction of profiled replay wall-clock per "
+                      "top-level stage").set(ps["share"], stage=stage)
                 g("replay_stage_mean_ms",
                   "mean wall-clock per replay-stage pass").set(
                     ps["mean_ms"], stage=stage)
@@ -1207,6 +1209,17 @@ class AlertMixPipeline:
                 c("replay_stage_ms_total",
                   "total wall-clock milliseconds per replay stage").sync(
                     ps["total_ms"], stage=stage)
+        # device launches per kernel and route (replay / drain / query),
+        # process-wide like the kernels' executables
+        for kernel, routes in kernel_launches().items():
+            for route, kc in routes.items():
+                c("kernel_launches_total",
+                  "kernel launches per route").sync(
+                    kc["launches"], kernel=kernel, route=route)
+                c("kernel_new_shapes_total",
+                  "kernel launches at a static shape not launched "
+                  "before in this process").sync(
+                    kc["new_shapes"], kernel=kernel, route=route)
         if self.query is not None:
             qs = self.query.status()
             c("query_queries_total",

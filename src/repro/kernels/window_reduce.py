@@ -90,5 +90,6 @@ def window_reduce_fwd(
         out_specs=pl.BlockSpec((4, block_s), lambda i, j: (0, i)),
         out_shape=jax.ShapeDtypeStruct((4, s_total), jnp.float32),
         interpret=interpret,
+        name="window_reduce",
     )(vals, segs)
     return out[:, :num_segments].T

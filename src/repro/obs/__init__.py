@@ -12,8 +12,13 @@ closes the loop the paper leaves to AWS:
                    and propagation on records — one document's journey
                    across ingest -> pipeline -> store -> delivery reads
                    back as one trace              (trace.py)
-  StageProfiler    always-on per-stage wall-clock breakdown (the
-                   batch-replay chain's 266x gap, itemized)  (profiler.py)
+  StageProfiler    always-on per-stage wall-clock breakdown of the
+                   batch-replay chain, each pass also a host event on
+                   the profiler's timeline and an entry in a ring of
+                   recent passes                 (profiler.py)
+  kernel_launches  device launches per kernel and route (replay,
+                   drain, query), with the new shapes each compiled
+                                                 (launches.py)
   MetricsConnector self-monitoring: registry snapshots re-enter the
                    platform as an ordinary stream on a ``__health__``
                    channel, so the EXISTING rule engine alarms on the
@@ -25,7 +30,9 @@ the plane as one unit (``AlertMixPipeline`` builds one from
 
 Import note: this package never imports ``repro.core`` / ``repro.store``
 at module level (they import *us*); ``selfmon`` — which needs the
-Connector data types — is imported lazily by its users.
+Connector data types — is imported lazily by its users.  Nor does it
+import JAX: host events on the profiler's timeline are opened only once
+something else has loaded JAX.
 """
 from __future__ import annotations
 
@@ -38,7 +45,8 @@ from repro.obs.metrics import (
     MetricsRegistry,
 )
 from repro.obs.latency import LatencySink, LatencyTracker
-from repro.obs.profiler import StageProfiler
+from repro.obs.launches import kernel_launches
+from repro.obs.profiler import StageProfiler, recent_passes
 from repro.obs.slo import SLOEngine, SLOSpec
 from repro.obs.trace import Span, TraceExporter, Tracer, TracingSink
 
@@ -67,4 +75,5 @@ __all__ = [
     "Counter", "Gauge", "Histogram", "LatencySink", "LatencyTracker",
     "MetricsRegistry", "Observability", "SLOEngine", "SLOSpec",
     "Span", "StageProfiler", "TraceExporter", "Tracer", "TracingSink",
+    "kernel_launches", "recent_passes",
 ]

@@ -1,0 +1,67 @@
+"""Kernel launch counters per route — which part of the program sends
+work to the device, how much, and how often at a shape never seen.
+
+Every launch that ``repro.kernels.ops`` makes of a kernel is counted
+under the route that asked for it:
+
+  replay   ``ReplayEngine.replay_log`` / ``replay_columns``
+  drain    ``ReplayEngine.replay_late_events`` (late-event journal)
+  query    ``QueryEngine`` cold-range scans
+  direct   any other caller (benchmarks, tests)
+
+Per (kernel, route) it keeps launches, the memberships N and slots S
+they carried, launches run in interpret mode, and new shapes: launches
+of a static shape this process had not launched before, each of which
+builds (or loads) one executable.  The counters are process-wide, as
+the kernels' compiled executables are, and never import JAX, so the
+metrics collector can read them from anywhere.
+"""
+from __future__ import annotations
+
+import threading
+from typing import Dict, Hashable, Set
+
+ROUTES = ("replay", "drain", "query", "direct")
+FIELDS = ("launches", "memberships", "slots", "new_shapes", "interpreted")
+
+
+class KernelLaunches:
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._counts: Dict[str, Dict[str, Dict[str, int]]] = {}
+        self._shapes: Dict[str, Set[Hashable]] = {}
+
+    def record(self, kernel: str, route: str, *, memberships: int,
+               slots: int, shape: Hashable, interpreted: bool) -> None:
+        """Count one launch of ``kernel`` for ``route``; ``shape`` is the
+        launch's static signature (what the jit cache keys on)."""
+        if route not in ROUTES:
+            raise ValueError(f"route must be one of {ROUTES}, not {route!r}")
+        with self._lock:
+            c = self._counts.setdefault(kernel, {}).get(route)
+            if c is None:
+                c = self._counts[kernel][route] = dict.fromkeys(FIELDS, 0)
+            seen = self._shapes.setdefault(kernel, set())
+            c["launches"] += 1
+            c["memberships"] += memberships
+            c["slots"] += slots
+            c["interpreted"] += int(interpreted)
+            if shape not in seen:
+                seen.add(shape)
+                c["new_shapes"] += 1
+
+    def snapshot(self) -> Dict[str, Dict[str, Dict[str, int]]]:
+        """{kernel: {route: {launches, memberships, slots, new_shapes,
+        interpreted}}}, a copy."""
+        with self._lock:
+            return {k: {r: dict(c) for r, c in routes.items()}
+                    for k, routes in self._counts.items()}
+
+
+#: the process's counters, fed by ``repro.kernels.ops``
+KERNEL_LAUNCHES = KernelLaunches()
+
+
+def kernel_launches() -> Dict[str, Dict[str, Dict[str, int]]]:
+    """Snapshot of the process's kernel launch counters."""
+    return KERNEL_LAUNCHES.snapshot()

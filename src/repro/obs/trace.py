@@ -16,9 +16,18 @@ Design constraints, in order:
                  ``span()`` to a shared no-op context manager — no
                  allocation, no clock reads, no behaviour change.
   cheap on       a sampled span is two ``perf_counter`` calls plus one
-                 append into a bounded deque (the flight recorder).
+                 append into a bounded deque (the flight recorder), and,
+                 once JAX is loaded, one ``jax.profiler.TraceAnnotation``
+                 of the span's name, so sampled spans (``ingest.fetch``,
+                 ``pipeline.process``, ``replay.late_events``,
+                 ``query.cold_scan``) share a profiler trace's timeline
+                 with the device ops.
   deterministic  sampling uses a seeded RNG and ids come from a
                  counter, so a traced replay is reproducible.
+
+Clocks: a span's ``start`` is read from the tracer's ``clock``, wall
+time (``time.time``) by default, so exported spans line up across
+processes; durations are measured on ``time.perf_counter``.
 
 The flight recorder is a ring of the last ``capacity`` finished spans
 (``spans()``, ``trace(trace_id)``, ``traces()``).  For durability,
@@ -39,6 +48,7 @@ import time
 from typing import Dict, List, Optional
 
 from repro.delivery.base import Sink, SinkClosedError
+from repro.obs.profiler import trace_annotation
 
 _perf = time.perf_counter
 
@@ -52,7 +62,7 @@ class Span:
 
     __slots__ = ("_tracer", "trace_id", "_sid", "_psid", "name", "start",
                  "duration_ms", "attrs", "error", "events", "_t0",
-                 "_onstack")
+                 "_onstack", "_ann")
 
     sampled = True
 
@@ -126,11 +136,16 @@ class Span:
                 local.stack.append(self)
             except AttributeError:
                 local.stack = [self]
+        ann = self._ann = trace_annotation(self.name)
+        if ann is not None:
+            ann.__enter__()
         self._t0 = _perf()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
         self.duration_ms = (_perf() - self._t0) * 1e3
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
         tracer = self._tracer
         if self._onstack:
             stack = tracer._local.stack

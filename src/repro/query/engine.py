@@ -290,7 +290,8 @@ class QueryEngine:
                 return {}
             aggs = reduce_columns(lanes.ts, lanes.key_codes, lanes.values,
                                   lanes.key_vocab, self.spec,
-                                  interpret=self.interpret, with_min=True)
+                                  interpret=self.interpret, with_min=True,
+                                  route="query")
         else:
             events = []
             for _off, payload in self.log.scan():
@@ -310,7 +311,8 @@ class QueryEngine:
             if not events:
                 return {}
             aggs = reduce_events(events, self.spec,
-                                 interpret=self.interpret, with_min=True)
+                                 interpret=self.interpret, with_min=True,
+                                 route="query")
         hot_slots = {(k, row[0], row[1])
                      for k, rows in hot.items() for row in rows}
         out: Dict[str, List[SegmentRow]] = {}

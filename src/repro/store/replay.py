@@ -70,8 +70,8 @@ class ReplayEngine:
                       "aggregates": 0, "alerts": 0}
         # always-on per-stage wall-clock breakdown of the batch-replay
         # chain (decode -> pack_events -> kernel -> unpack -> state_merge
-        # [-> redeliver]); surfaced via status()["profile"] — ROADMAP
-        # item 1's 266x replay-vs-live gap, itemized
+        # [-> redeliver], with pack_events.* and kernel.* sub-stages);
+        # surfaced via status()["profile"] and the replay_stage_* metrics
         self.profiler = StageProfiler("replay")
         # optional repro.obs.Tracer (the pipeline mounts its own)
         self.tracer = None
@@ -169,10 +169,12 @@ class ReplayEngine:
 
     # ---- route 2: batch-path aggregation into the live rule engine ----------
     def replay_events(self, events: Sequence[Event], *,
-                      watermark: Optional[float] = None) -> tuple:
+                      watermark: Optional[float] = None,
+                      route: str = "replay") -> tuple:
         """Run raw events through pack_events -> window_reduce -> the
         live RuleEngine.  Returns (aggregates, fired alerts).  Sessions
-        have no static slot layout — use the incremental operator."""
+        have no static slot layout — use the incremental operator.
+        ``route`` names the caller for the kernel's launch counters."""
         if self.analytics is None:
             raise RuntimeError("no AnalyticsStage attached")
         from repro.alerts.batch import reduce_events
@@ -187,7 +189,8 @@ class ReplayEngine:
             # and the rules as whole aggregates; a window made only of
             # replayed events would otherwise carry min=+inf
             aggs = reduce_events(events, spec, interpret=self.interpret,
-                                 profiler=self.profiler, with_min=True)
+                                 profiler=self.profiler, with_min=True,
+                                 route=route)
             wm = watermark if watermark is not None \
                 else self.analytics.operator.watermark
             for a in aggs:
@@ -224,7 +227,8 @@ class ReplayEngine:
             aggs = reduce_columns(lanes.ts, lanes.key_codes, lanes.values,
                                   lanes.key_vocab, spec,
                                   interpret=self.interpret,
-                                  profiler=self.profiler, with_min=True)
+                                  profiler=self.profiler, with_min=True,
+                                  route="replay")
             wm = watermark if watermark is not None \
                 else self.analytics.operator.watermark
             for a in aggs:
@@ -298,7 +302,8 @@ class ReplayEngine:
                 last = off + 1
         if not events:
             return {"events": 0, "aggregates": 0, "alerts": 0}
-        aggs, fired = self.replay_events(events, watermark=watermark)
+        aggs, fired = self.replay_events(events, watermark=watermark,
+                                         route="drain")
         self.journal.advance("late_event", last)
         return {"events": len(events), "aggregates": len(aggs),
                 "alerts": len(fired)}

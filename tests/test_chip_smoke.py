@@ -26,27 +26,30 @@ _spec.loader.exec_module(cs)
 
 
 def test_pipeline_routes_match_their_references(tmp_path):
-    rec = cs.LaunchRecorder()
-    with rec.installed():
-        p = cs.build_pipeline(str(tmp_path), num_sources=300, workers=1,
-                              resizer=False, segment_bytes=4096,
-                              query_max_windows_per_key=4)
-        try:
-            late = cs.run_with_late_drains(p, rec, virtual_s=1800.0,
-                                           chunk_s=600.0, per_worker=1)
-            cold = cs.check_cold_query(p, rec)
-            replay = cs.check_log_replay(p, rec)
-        finally:
-            p.close()
+    before = cs.launch_counts()
+    p = cs.build_pipeline(str(tmp_path), num_sources=300, workers=1,
+                          resizer=False, segment_bytes=4096,
+                          query_max_windows_per_key=4)
+    try:
+        late = cs.run_with_late_drains(p, virtual_s=1800.0, chunk_s=600.0,
+                                       per_worker=1)
+        cold = cs.check_cold_query(p)
+        replay = cs.check_log_replay(p)
+    finally:
+        p.close()
     assert late["late_events"] > 0 and late["drains"] > 0
     assert late["slots"] > 0
     assert cold["windows"] > 0 and cold["floor"] > 0
     assert replay["events"] > 0 and replay["slots"] > 0
-    per_route = rec.per_route()
-    for route in ("late_drain", "cold_query", "log_replay"):
-        assert per_route[route] > 0, route
-    # on the CPU the kernel runs in interpret mode, chosen explicitly
-    assert {i for _, _, _, i in rec.launches} == {True}
+    counts = cs.launches_since(before)
+    for route in ("drain", "query", "replay"):
+        assert counts[route]["launches"] > 0, route
+        # on the CPU the kernel runs in interpret mode, chosen explicitly
+        assert counts[route]["interpreted"] == counts[route]["launches"]
+    assert "direct" not in counts
+    # with min: two launches per replay, of one shape
+    assert counts["replay"]["launches"] == 2
+    assert counts["replay"]["new_shapes"] <= 1
 
 
 def test_direct_and_sliding_batches_match_the_reference():
@@ -58,10 +61,13 @@ def test_direct_and_sliding_batches_match_the_reference():
                   key_codes=rng.integers(0, 4, n).astype(np.int64),
                   key_vocab=["news", "custom_rss", "facebook", "twitter"],
                   values=np.ones(n))
-    rec = cs.LaunchRecorder()
-    with rec.installed():
-        res = cs.check_direct(rec, lanes, n_events=3000, n_segments=100)
-    assert rec.per_route() == {"direct": 2}
+    before = cs.launch_counts()
+    res = cs.check_direct(lanes, n_events=3000, n_segments=100)
+    counts = cs.launches_since(before)
+    assert list(counts) == ["direct"]
+    assert counts["direct"]["launches"] == 2
+    assert counts["direct"]["memberships"] == 3000 + res["sliding_events"]
+    assert counts["direct"]["slots"] == 100 + res["sliding_slots"]
     assert res["slots"] > 100
     assert res["sliding_events"] > n       # each event in several windows
     assert res["sum_err_over_bound"] <= 1.0

@@ -538,3 +538,45 @@ def test_min_lane_live_and_batch_agree():
     for slot, (mn, mx) in live.items():
         assert batch[slot][0] == pytest.approx(mn, rel=1e-6)
         assert batch[slot][1] == pytest.approx(mx, rel=1e-6)
+
+
+def test_cold_query_launches_count_under_the_query_route():
+    """A cold-range query counts its kernel launches under ``query``;
+    the same query again launches the same shape, not a new one."""
+    import tempfile
+
+    from repro.obs import kernel_launches
+
+    def counts():
+        return kernel_launches().get("window_reduce", {})
+
+    def grew(a, b, route, key):
+        return b.get(route, {}).get(key, 0) - a.get(route, {}).get(key, 0)
+
+    pipe = AlertMixPipeline(
+        PipelineConfig(num_sources=200, analytics=True, query=True,
+                       store_dir=tempfile.mkdtemp(), store_columnar=True,
+                       columnar_block_rows=64, segment_bytes=1 << 14,
+                       window_size_s=60.0, query_max_windows_per_key=5),
+        seed=0)
+    try:
+        pipe.run_for(2400.0)
+        q = AggQuery(channel="news", start=0.0, end=2400.0, agg="min")
+        c0 = counts()
+        pipe.query.query(q, use_cache=False)
+        c1 = counts()
+        pipe.query.query(q, use_cache=False)
+        c2 = counts()
+    finally:
+        pipe.close()
+    assert grew(c0, c1, "query", "launches") == 2      # max and min lanes
+    # one shape for both lanes (none new if this process launched it)
+    assert grew(c0, c1, "query", "new_shapes") <= 1
+    assert c1["query"]["new_shapes"] >= 1
+    assert grew(c1, c2, "query", "launches") == 2
+    assert grew(c1, c2, "query", "new_shapes") == 0
+    for route in ("replay", "drain"):
+        assert grew(c0, c2, route, "launches") == 0
+    text = pipe.metrics_text()
+    assert 'kernel_launches_total{kernel="window_reduce",route="query"}' \
+        in text

@@ -746,3 +746,135 @@ def test_subscription_wait_long_poll_with_producer_thread():
     cb = hub.subscribe(callback=lambda r: None)
     with pytest.raises(RuntimeError):
         cb.wait(0.01)
+
+
+# ---------------------------------------------------------------------------
+# the replay chain on the profiler's timeline, and launches per route
+# ---------------------------------------------------------------------------
+
+def _columnar_replay_engine(tmp_path, n=200, **kw):
+    from repro.store.columnar.log import ColumnarEventLog
+
+    log = ColumnarEventLog(str(tmp_path / "clog"), segment_bytes=4096,
+                           block_rows=16)
+    log.append([{"id": f"d{i}",
+                 "doc": {"title": "t", "published_at": float(i % 900),
+                         "channel": "news" if i % 2 else "sports",
+                         "value": float(i % 7)}} for i in range(n)])
+    log.roll()
+    return ReplayEngine(log=log, analytics=AnalyticsStage(
+        WindowSpec(size_s=60.0), []), interpret=True, **kw)
+
+
+def _host_events(trace_dir):
+    import glob
+
+    from jax.profiler import ProfileData
+
+    files = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                      recursive=True)
+    assert files, "the profiler wrote no trace"
+    out = set()
+    for plane in ProfileData.from_file(files[-1]).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                out.update(e.name for e in line.events)
+    return out
+
+
+def test_replay_log_stages_are_host_events_on_the_profiler_trace(tmp_path):
+    """A profiler trace of one columnar ``replay_log`` names every stage
+    and sub-stage of the chain, and the kernel's launches by route."""
+    import jax
+
+    eng = _columnar_replay_engine(tmp_path)
+    eng.replay_log(0, watermark=1e9)              # compile outside
+    jax.profiler.start_trace(str(tmp_path / "trace"))
+    try:
+        res = eng.replay_log(0, watermark=1e9)
+    finally:
+        jax.profiler.stop_trace()
+    assert res["columnar"] and res["events"] == 200
+    names = _host_events(str(tmp_path / "trace"))
+    for stage in ("decode", "pack_events", "pack_events.assign",
+                  "pack_events.unique", "pack_events.slots", "kernel",
+                  "kernel.dispatch", "kernel.wait", "kernel.fetch",
+                  "unpack", "state_merge"):
+        assert f"replay.{stage}" in names, stage
+    assert "window_reduce.replay" in names
+
+
+def test_replay_sub_stages_nest_inside_their_stages(tmp_path):
+    """The pack and kernel stages keep their meaning: each covers its
+    sub-stages, and the min lane's launch starts after the max lane's
+    fetch ended."""
+    from repro.obs.profiler import recent_passes
+
+    eng = _columnar_replay_engine(tmp_path)
+    eng.replay_log(0, watermark=1e9)
+    n = len(recent_passes())
+    eng.replay_log(0, watermark=1e9)
+    passes = [(st, t0, t0 + dt) for prof, st, t0, dt in recent_passes()[n:]
+              if prof == "replay"]
+    span = {st: (a, b) for st, a, b in passes if "." not in st}
+    for parent in ("pack_events", "kernel"):
+        a, b = span[parent]
+        subs = [(x, y) for st, x, y in passes
+                if st.startswith(parent + ".")]
+        assert len(subs) == (3 if parent == "pack_events" else 6)
+        assert all(a <= x <= y <= b for x, y in subs)
+    kernel = [(st, x) for st, x, _ in passes if st.startswith("kernel.")]
+    assert [st for st, _ in kernel] == [
+        "kernel.dispatch", "kernel.wait", "kernel.fetch"] * 2
+    assert [x for _, x in kernel] == sorted(x for _, x in kernel)
+
+
+def test_launch_counters_tell_replay_and_drain_apart(tmp_path):
+    """A log replay and a late-event drain count their launches under
+    their own route; replaying the same log again launches the same
+    shape, which is not a new one."""
+    from repro.obs import kernel_launches
+
+    def counts():
+        return kernel_launches().get("window_reduce", {})
+
+    j = DeadLetterJournal(str(tmp_path / "j"))
+    eng = _columnar_replay_engine(tmp_path, journal=j)
+    c0 = counts()
+    eng.replay_log(0, watermark=1e9)
+    c1 = counts()
+    eng.replay_log(0, watermark=1e9)
+    c2 = counts()
+
+    def grew(a, b, route, key):
+        return b.get(route, {}).get(key, 0) - a.get(route, {}).get(key, 0)
+
+    assert grew(c0, c1, "replay", "launches") == 2     # max and min lanes
+    assert grew(c0, c1, "replay", "memberships") == 400
+    assert grew(c1, c2, "replay", "launches") == 2
+    assert grew(c1, c2, "replay", "new_shapes") == 0
+    assert grew(c0, c2, "drain", "launches") == 0
+    for t in (5.0, 6.0, 7.0):
+        j.record("late_event", {"key": "news", "event_time": t,
+                                "value": 2.0})
+    assert eng.replay_late_events()["events"] == 3
+    c3 = counts()
+    assert grew(c2, c3, "drain", "launches") == 2
+    assert grew(c2, c3, "drain", "slots") == 2          # one slot a lane
+    assert grew(c2, c3, "replay", "launches") == 0
+
+
+def test_pipeline_exports_kernel_launches_per_route(tmp_path):
+    cfg = PipelineConfig(num_sources=0, analytics=True,
+                         store_dir=str(tmp_path / "store"))
+    p = AlertMixPipeline(cfg, seed=0)
+    try:
+        p.store.replay.replay_events([("news", 10.0, 1.0)], watermark=1e9,
+                                     route="drain")
+        text = p.metrics_text()
+        assert ('kernel_launches_total{kernel="window_reduce",'
+                'route="drain"}') in text
+        assert ('kernel_new_shapes_total{kernel="window_reduce",'
+                'route="drain"}') in text
+    finally:
+        p.close()
