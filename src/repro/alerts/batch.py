@@ -18,9 +18,16 @@ import numpy as np
 
 from repro.alerts.windows import (SESSION, SLIDING, TUMBLING,
                                   WindowAggregate, WindowSpec)
+from repro.obs.launches import record_pack_slot_index
 
 Event = Tuple[str, float, float]          # (key, event_time, value)
 Slot = Tuple[str, float, float]           # (key, window_start, window_end)
+
+# pack_columns indexes its (start, code) keys with a presence table and
+# its running count (5 bytes a key of the range, in place of a sort of
+# the memberships' keys) when the range is at most this many times the
+# memberships; past it, sorting the keys costs less memory and time
+_DENSE_KEYS_PER_MEMBERSHIP = 4
 
 
 def pack_events(events: Sequence[Event], spec: WindowSpec):
@@ -57,10 +64,23 @@ def pack_columns(ts: np.ndarray, key_codes: np.ndarray,
     two packers are bit-identical — the hot/cold dedup in the query
     plane depends on it.
 
+    Slots come in (start, key code) order, each membership's seg id is
+    its slot's index in that order.  The slot index is built on one
+    int64 key, ``rank(start) * K + code - min(code)`` with ``K`` the
+    codes' range, which keeps that order: the starts are ranked
+    exactly (``np.unique`` of the float64 column, a sort on both
+    paths, then ``searchsorted``), and the keys are indexed by a
+    presence table and its running count when the key's range is at
+    most ``_DENSE_KEYS_PER_MEMBERSHIP`` times the memberships (path
+    ``dense``, which does not sort the keys), else by ``np.unique`` of
+    the keys (path ``sort``).  Each call counts its path in
+    ``repro.obs.pack_slot_index()``.
+
     ``profiler`` times the three steps as sub-stages of ``pack_events``:
-    ``pack_events.assign`` (window assignment, expansion and the column
-    stack), ``pack_events.unique`` (the (start, key) ``np.unique`` and
-    its inverse) and ``pack_events.slots`` (the Python slot list)."""
+    ``pack_events.assign`` (window assignment and expansion),
+    ``pack_events.unique`` (ranking the starts, the integer key, its
+    index and inverse, and each slot's start and code) and
+    ``pack_events.slots`` (the Python slot list)."""
     if spec.kind == SESSION:
         raise ValueError("session windows have no static slot layout; "
                          "use WindowOperator")
@@ -92,15 +112,30 @@ def pack_columns(ts: np.ndarray, key_codes: np.ndarray,
             estarts = np.concatenate(parts_s)
             ecodes = np.concatenate(parts_c)
             evals = np.concatenate(parts_v)
-        # one (key, start) slot per distinct pair; codes fit float64 exactly
-        combo = np.column_stack([estarts, ecodes.astype(np.float64)])
         packed = evals.astype(np.float32)
     with stage("pack_events.unique"):
-        uniq, inv = np.unique(combo, axis=0, return_inverse=True)
-        seg_ids = inv.astype(np.int32).ravel()
+        # one slot per distinct (start, code) pair, in that order
+        starts = np.unique(estarts)
+        lo = int(ecodes.min())
+        k = int(ecodes.max()) - lo + 1
+        key = np.searchsorted(starts, estarts) * k + (ecodes - lo)
+        span = starts.size * k
+        if span <= _DENSE_KEYS_PER_MEMBERSHIP * key.size:
+            path = "dense"
+            present = np.zeros(span, bool)
+            present[key] = True
+            seg_ids = np.cumsum(present, dtype=np.int32)[key] - 1
+            ukey = np.flatnonzero(present)
+        else:
+            path = "sort"
+            ukey, inv = np.unique(key, return_inverse=True)
+            seg_ids = inv.astype(np.int32)
+        ustarts = starts[ukey // k]
+        ucodes = ukey % k + lo
+    record_pack_slot_index(path)
     with stage("pack_events.slots"):
-        slots = [(int(c), float(s), float(s) + spec.size_s)
-                 for s, c in uniq]
+        slots = [(c, s, s + spec.size_s)
+                 for s, c in zip(ustarts.tolist(), ucodes.tolist())]
     return (packed, seg_ids, slots)
 
 

@@ -67,7 +67,8 @@ from repro.core.scheduler import DEFAULT_CHANNELS, ChannelDistributor, Scheduler
 from repro.core.sinks import IndexSink
 from repro.core.sources import NOT_MODIFIED, SourceSimulator
 from repro.delivery import BatchingSink, FanOutSink, RetryingSink, as_sink
-from repro.obs import LatencySink, Observability, TracingSink, kernel_launches
+from repro.obs import (LatencySink, Observability, TracingSink,
+                       kernel_launches, pack_slot_index)
 
 # repro.ingest imports repro.core.registry (which runs this package's
 # __init__) — import it lazily to keep `import repro.ingest` first legal
@@ -1220,6 +1221,10 @@ class AlertMixPipeline:
                   "kernel launches at a static shape not launched "
                   "before in this process").sync(
                     kc["new_shapes"], kernel=kernel, route=route)
+        for path, n in pack_slot_index().items():
+            c("pack_slot_index_total",
+              "column packs per slot-index path (dense table or "
+              "sort)").sync(n, path=path)
         if self.query is not None:
             qs = self.query.status()
             c("query_queries_total",

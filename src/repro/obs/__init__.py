@@ -17,8 +17,9 @@ closes the loop the paper leaves to AWS:
                    the profiler's timeline and an entry in a ring of
                    recent passes                 (profiler.py)
   kernel_launches  device launches per kernel and route (replay,
-                   drain, query), with the new shapes each compiled
-                                                 (launches.py)
+                   drain, query), with the new shapes each compiled;
+                   beside it pack_slot_index, column packs per
+                   slot-index path (dense, sort)  (launches.py)
   MetricsConnector self-monitoring: registry snapshots re-enter the
                    platform as an ordinary stream on a ``__health__``
                    channel, so the EXISTING rule engine alarms on the
@@ -45,7 +46,7 @@ from repro.obs.metrics import (
     MetricsRegistry,
 )
 from repro.obs.latency import LatencySink, LatencyTracker
-from repro.obs.launches import kernel_launches
+from repro.obs.launches import kernel_launches, pack_slot_index
 from repro.obs.profiler import StageProfiler, recent_passes
 from repro.obs.slo import SLOEngine, SLOSpec
 from repro.obs.trace import Span, TraceExporter, Tracer, TracingSink
@@ -75,5 +76,5 @@ __all__ = [
     "Counter", "Gauge", "Histogram", "LatencySink", "LatencyTracker",
     "MetricsRegistry", "Observability", "SLOEngine", "SLOSpec",
     "Span", "StageProfiler", "TraceExporter", "Tracer", "TracingSink",
-    "kernel_launches", "recent_passes",
+    "kernel_launches", "pack_slot_index", "recent_passes",
 ]

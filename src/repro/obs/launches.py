@@ -12,9 +12,16 @@ under the route that asked for it:
 Per (kernel, route) it keeps launches, the memberships N and slots S
 they carried, launches run in interpret mode, and new shapes: launches
 of a static shape this process had not launched before, each of which
-builds (or loads) one executable.  The counters are process-wide, as
-the kernels' compiled executables are, and never import JAX, so the
-metrics collector can read them from anywhere.
+builds (or loads) one executable.
+
+Beside them, ``pack_slot_index()`` counts the column packs
+(``repro.alerts.batch.pack_columns``) by the path that built their
+(window start, key) slot index: ``dense`` (a presence table over the
+key's range) or ``sort`` (a sort of the keys).
+
+The counters are process-wide, as the kernels' compiled executables
+are, and never import JAX, so the metrics collector can read them from
+anywhere.
 """
 from __future__ import annotations
 
@@ -65,3 +72,21 @@ KERNEL_LAUNCHES = KernelLaunches()
 def kernel_launches() -> Dict[str, Dict[str, Dict[str, int]]]:
     """Snapshot of the process's kernel launch counters."""
     return KERNEL_LAUNCHES.snapshot()
+
+
+PACK_PATHS = ("dense", "sort")
+_pack_lock = threading.Lock()
+_pack_paths: Dict[str, int] = dict.fromkeys(PACK_PATHS, 0)
+
+
+def record_pack_slot_index(path: str) -> None:
+    """Count one column pack whose slot index took ``path``, one of
+    ``PACK_PATHS``."""
+    with _pack_lock:
+        _pack_paths[path] += 1
+
+
+def pack_slot_index() -> Dict[str, int]:
+    """Snapshot of the process's column packs per slot-index path."""
+    with _pack_lock:
+        return dict(_pack_paths)

@@ -658,6 +658,38 @@ def test_replay_stage_profile_exported_as_registry_gauges(tmp_path):
     p.close()
 
 
+def test_pack_slot_index_paths_exported_after_columnar_replay(tmp_path):
+    """A columnar log replay packs its columns once; the path its slot
+    index took is counted, process-wide, and every scrape exports
+    ``pack_slot_index_total{path}`` beside ``kernel_launches_total``."""
+    from repro.obs import pack_slot_index
+
+    p = AlertMixPipeline(
+        PipelineConfig(num_sources=0, analytics=True, store_columnar=True,
+                       store_dir=str(tmp_path / "s")), seed=0)
+    try:
+        p.store.append_documents(
+            [(f"d{i}", {"title": "t", "published_at": float(i % 600),
+                        "channel": "news" if i % 3 else "sports"})
+             for i in range(120)])
+        p.store.log.roll()
+        before = pack_slot_index()
+        res = p.store.replay.replay_log(0, watermark=1e9)
+        after = pack_slot_index()
+        assert res["columnar"] and res["events"] == 120
+        assert after["dense"] - before["dense"] == 1
+        assert after["sort"] == before["sort"]
+        text = p.metrics_text()
+        for path in ("dense", "sort"):
+            assert f'pack_slot_index_total{{path="{path}"}}' in text
+        assert 'kernel_launches_total{kernel="window_reduce",' \
+               'route="replay"}' in text
+        assert p.obs.metrics.counter("pack_slot_index_total").value(
+            path="dense") == after["dense"]
+    finally:
+        p.close()
+
+
 def test_rule_engine_add_rule_rejects_duplicates():
     from repro.alerts import RuleEngine, ThresholdRule
 
