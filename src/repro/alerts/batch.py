@@ -3,8 +3,9 @@
 ``pack_events`` flattens (key, event_time, value) triples into the flat
 ``values`` / ``seg_ids`` tensors ``repro.kernels.ops.window_reduce``
 consumes (one segment per distinct (key, window) slot — sliding windows
-replicate an event into every covering slot), and ``reduce_events`` turns
-the kernel's (S, 4) count/sum/sumsq/max lanes back into
+replicate an event into every covering slot, and session windows make
+one slot per session the batch's events form), and ``reduce_events``
+turns the kernel's (S, 4) count/sum/sumsq/max lanes back into
 ``WindowAggregate`` records.  This is the batch/replay path — reprocessing
 a backlog of documents at hardware speed — complementing the incremental
 ``WindowOperator`` used on the live path; both produce identical
@@ -18,7 +19,7 @@ import numpy as np
 
 from repro.alerts.windows import (SESSION, SLIDING, TUMBLING,
                                   WindowAggregate, WindowSpec)
-from repro.obs.launches import record_pack_slot_index
+from repro.obs.launches import record_pack_sessions, record_pack_slot_index
 
 Event = Tuple[str, float, float]          # (key, event_time, value)
 Slot = Tuple[str, float, float]           # (key, window_start, window_end)
@@ -34,11 +35,16 @@ def pack_events(events: Sequence[Event], spec: WindowSpec):
     """-> (values f32 (N,), seg_ids i32 (N,), slots list[Slot]).
 
     N >= len(events): sliding windows fan each event out to every slot
-    covering it.  Session windows are data-driven and stay on the
-    incremental operator."""
+    covering it.  Session windows take ``pack_columns``' session layout,
+    over the keys coded in sorted order, so slots come in (start, key)
+    order."""
     if spec.kind == SESSION:
-        raise ValueError("session windows have no static slot layout; "
-                         "use WindowOperator")
+        vocab, codes = np.unique(np.asarray([e[0] for e in events], object),
+                                 return_inverse=True)
+        packed, seg_ids, slots = pack_columns(
+            np.asarray([e[1] for e in events], np.float64), codes,
+            np.asarray([e[2] for e in events], np.float64), spec)
+        return packed, seg_ids, [(vocab[c], s, e) for c, s, e in slots]
     slot_ids: Dict[Slot, int] = {}
     vals: List[float] = []
     segs: List[int] = []
@@ -76,15 +82,17 @@ def pack_columns(ts: np.ndarray, key_codes: np.ndarray,
     the keys (path ``sort``).  Each call counts its path in
     ``repro.obs.pack_slot_index()``.
 
+    Session windows take their own layout (``_pack_sessions``): one
+    slot per session, whose end is its last event plus ``gap_s``.
+
     ``profiler`` times the three steps as sub-stages of ``pack_events``:
     ``pack_events.assign`` (window assignment and expansion),
     ``pack_events.unique`` (ranking the starts, the integer key, its
     index and inverse, and each slot's start and code) and
     ``pack_events.slots`` (the Python slot list)."""
-    if spec.kind == SESSION:
-        raise ValueError("session windows have no static slot layout; "
-                         "use WindowOperator")
     stage = _stage_of(profiler)
+    if spec.kind == SESSION:
+        return _pack_sessions(ts, key_codes, values, spec.gap_s, stage)
     with stage("pack_events.assign"):
         ts = np.asarray(ts, np.float64)
         codes = np.asarray(key_codes, np.int64)
@@ -136,6 +144,63 @@ def pack_columns(ts: np.ndarray, key_codes: np.ndarray,
     with stage("pack_events.slots"):
         slots = [(c, s, s + spec.size_s)
                  for s, c in zip(ustarts.tolist(), ucodes.tolist())]
+    return (packed, seg_ids, slots)
+
+
+def _session_opens(codes: np.ndarray, ts: np.ndarray, gap_s: float):
+    """Over events sorted by (code, ts) -> (by_key, by_gap): whether each
+    event opens a session because its key differs from the previous
+    event's, or because it comes more than ``gap_s`` after it.  The gap
+    test is the live operator's closed-interval overlap, ``previous +
+    gap_s < t``, so events exactly ``gap_s`` apart share a session."""
+    by_key = np.ones(ts.size, bool)
+    by_key[1:] = codes[1:] != codes[:-1]
+    by_gap = np.zeros(ts.size, bool)
+    by_gap[1:] = ~by_key[1:] & (ts[:-1] + gap_s < ts[1:])
+    return by_key, by_gap
+
+
+def _pack_sessions(ts, key_codes, values, gap_s: float, stage):
+    """``pack_columns`` for session windows.
+
+    The events are sorted by (code, event time) and cut where the code
+    changes or the next event comes more than ``gap_s`` later
+    (``_session_opens``).  A session's start is its first event and its
+    end its last event plus ``gap_s``, as the live operator merges them;
+    within a key, sessions are disjoint, so (start, code) is unique and
+    the slots come in that order, like the other kinds'.  Each call
+    counts the ``session`` path in ``repro.obs.pack_slot_index()`` and
+    its sessions, by what opened them, in ``repro.obs.pack_sessions()``.
+
+    Timed as ``pack_events.sessions`` (the sort, the cut, the seg ids
+    and each session's start and end) and ``pack_events.slots`` (the
+    Python slot list)."""
+    with stage("pack_events.sessions"):
+        ts = np.asarray(ts, np.float64)
+        codes = np.asarray(key_codes, np.int64)
+        if ts.size == 0:
+            return (np.empty(0, np.float32), np.empty(0, np.int32), [])
+        packed = np.asarray(values, np.float64).astype(np.float32)
+        order = np.lexsort((ts, codes))
+        c, t = codes[order], ts[order]
+        by_key, by_gap = _session_opens(c, t, gap_s)
+        opens = by_key | by_gap
+        first = np.flatnonzero(opens)
+        last = np.append(first[1:], t.size) - 1
+        starts, ends, scodes = t[first], t[last] + gap_s, c[first]
+        # sessions in (start, code) order; each event's seg id is its
+        # session's index in that order
+        slot_order = np.lexsort((scodes, starts))
+        rank = np.empty(first.size, np.int32)
+        rank[slot_order] = np.arange(first.size, dtype=np.int32)
+        seg_ids = np.empty(t.size, np.int32)
+        seg_ids[order] = rank[np.cumsum(opens) - 1]
+    record_pack_slot_index("session")
+    record_pack_sessions(key=int(by_key.sum()), gap=int(by_gap.sum()))
+    with stage("pack_events.slots"):
+        slots = list(zip(scodes[slot_order].tolist(),
+                         starts[slot_order].tolist(),
+                         ends[slot_order].tolist()))
     return (packed, seg_ids, slots)
 
 

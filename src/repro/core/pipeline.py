@@ -68,7 +68,7 @@ from repro.core.sinks import IndexSink
 from repro.core.sources import NOT_MODIFIED, SourceSimulator
 from repro.delivery import BatchingSink, FanOutSink, RetryingSink, as_sink
 from repro.obs import (LatencySink, Observability, TracingSink,
-                       kernel_launches, pack_slot_index)
+                       kernel_launches, pack_sessions, pack_slot_index)
 
 # repro.ingest imports repro.core.registry (which runs this package's
 # __init__) — import it lazily to keep `import repro.ingest` first legal
@@ -102,6 +102,8 @@ class PipelineConfig:
     analytics: bool = False            # mount the windowed-analytics stage
     window_kind: str = "tumbling"      # tumbling | sliding | session
     window_size_s: float = 300.0       # event-time window width
+    window_gap_s: float = 30.0         # session only: idle gap that
+                                       # closes a key's session
     # the lateness budget must cover the fetch cadence: a document can be
     # published right after one conditional GET and only be seen ~one
     # feed_interval_s later, which is event-time lateness by construction
@@ -454,6 +456,7 @@ class AlertMixPipeline:
                 rules = []      # self-monitoring/query only: no product rules
             self.analytics = AnalyticsStage(
                 WindowSpec(kind=cfg.window_kind, size_s=cfg.window_size_s,
+                           gap_s=cfg.window_gap_s,
                            allowed_lateness_s=cfg.allowed_lateness_s),
                 rules,
                 watermark_lag_s=cfg.watermark_lag_s,
@@ -1033,8 +1036,9 @@ class AlertMixPipeline:
         are complete up to ``now``).  With a store plane + analytics
         mounted, the journal's ``late_event`` backlog is drained through
         the batch path here too — late data joins the same rule state
-        instead of rotting on disk (sessions excluded: no static slot
-        layout for the kernel path)."""
+        instead of rotting on disk (sessions excluded: the batch path
+        cuts late events into sessions of their own, and merging them
+        into sessions already closed is another semantics)."""
         if (self.store is not None and self.analytics is not None
                 and self.cfg.replay_late_on_flush
                 and self.analytics.operator.spec.kind != "session"):
@@ -1223,8 +1227,12 @@ class AlertMixPipeline:
                     kc["new_shapes"], kernel=kernel, route=route)
         for path, n in pack_slot_index().items():
             c("pack_slot_index_total",
-              "column packs per slot-index path (dense table or "
-              "sort)").sync(n, path=path)
+              "column packs per slot-index path (dense table, sort "
+              "or session layout)").sync(n, path=path)
+        for cut, n in pack_sessions().items():
+            c("pack_sessions_total",
+              "sessions laid out by session packs, by what opened "
+              "them (a new key or a gap)").sync(n, cut=cut)
         if self.query is not None:
             qs = self.query.status()
             c("query_queries_total",

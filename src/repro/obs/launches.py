@@ -17,7 +17,10 @@ builds (or loads) one executable.
 Beside them, ``pack_slot_index()`` counts the column packs
 (``repro.alerts.batch.pack_columns``) by the path that built their
 (window start, key) slot index: ``dense`` (a presence table over the
-key's range) or ``sort`` (a sort of the keys).
+key's range), ``sort`` (a sort of the keys) or ``session`` (the session
+layout: a sort by key and event time, cut at idle gaps), and
+``pack_sessions()`` counts the sessions those session packs laid out by
+what opened each: a new ``key`` or a ``gap`` longer than the spec's.
 
 The counters are process-wide, as the kernels' compiled executables
 are, and never import JAX, so the metrics collector can read them from
@@ -74,7 +77,7 @@ def kernel_launches() -> Dict[str, Dict[str, Dict[str, int]]]:
     return KERNEL_LAUNCHES.snapshot()
 
 
-PACK_PATHS = ("dense", "sort")
+PACK_PATHS = ("dense", "sort", "session")
 _pack_lock = threading.Lock()
 _pack_paths: Dict[str, int] = dict.fromkeys(PACK_PATHS, 0)
 
@@ -90,3 +93,22 @@ def pack_slot_index() -> Dict[str, int]:
     """Snapshot of the process's column packs per slot-index path."""
     with _pack_lock:
         return dict(_pack_paths)
+
+
+SESSION_CUTS = ("key", "gap")
+_session_cuts: Dict[str, int] = dict.fromkeys(SESSION_CUTS, 0)
+
+
+def record_pack_sessions(key: int, gap: int) -> None:
+    """Count the sessions one session pack laid out: ``key`` opened by
+    a key's first event, ``gap`` by an event more than the gap after
+    the key's previous one."""
+    with _pack_lock:
+        _session_cuts["key"] += key
+        _session_cuts["gap"] += gap
+
+
+def pack_sessions() -> Dict[str, int]:
+    """Snapshot of the process's packed sessions per cut."""
+    with _pack_lock:
+        return dict(_session_cuts)

@@ -35,6 +35,7 @@ from collections import OrderedDict
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence, Tuple
 
+from repro.alerts.windows import SESSION
 from repro.query.store import MaterializedStore, SegmentRow
 
 AGGS = ("count", "sum", "mean", "max", "min", "stddev", "rate")
@@ -253,9 +254,19 @@ class QueryEngine:
                    hot: Dict[str, List[SegmentRow]]) -> Dict[str, List[SegmentRow]]:
         """Recompute evicted windows from the EventLog via the Pallas
         batch path.  Hot wins: slots still materialized are skipped so
-        overlap never double-counts."""
+        overlap never double-counts.
+
+        Session specs are refused: the scan reads the range widened by a
+        fixed slack, and no fixed slack bounds a session, so the sessions
+        at the range's edges would come out cut short."""
         if self.spec is None:
             return {}
+        if self.spec.kind == SESSION:
+            raise ValueError(
+                f"cold queries cannot serve session windows: range "
+                f"[{q.start}, {q.end}) reaches below the hot store's floor "
+                f"{self.store.floor}, and a session evicted there cannot "
+                f"be recomputed from a bounded scan of the log")
         if self.tracer is not None:
             with self.tracer.span("query.cold_scan",
                                   attrs={"channel": q.channel}) as sp:

@@ -172,8 +172,8 @@ class ReplayEngine:
                       watermark: Optional[float] = None,
                       route: str = "replay") -> tuple:
         """Run raw events through pack_events -> window_reduce -> the
-        live RuleEngine.  Returns (aggregates, fired alerts).  Sessions
-        have no static slot layout — use the incremental operator.
+        live RuleEngine.  Returns (aggregates, fired alerts).  Session
+        specs are cut into the sessions these events alone form.
         ``route`` names the caller for the kernel's launch counters."""
         if self.analytics is None:
             raise RuntimeError("no AnalyticsStage attached")
@@ -214,7 +214,8 @@ class ReplayEngine:
                        watermark: Optional[float] = None) -> tuple:
         """Run column lanes (``ColumnarEventLog.scan_lanes`` output)
         through pack_columns -> window_reduce -> the live RuleEngine —
-        the zero-per-record-Python twin of ``replay_events``."""
+        the zero-per-record-Python twin of ``replay_events``, for every
+        window kind (session specs take the session layout)."""
         if self.analytics is None:
             raise RuntimeError("no AnalyticsStage attached")
         from repro.alerts.batch import reduce_columns

@@ -344,6 +344,24 @@ def test_cold_query_without_store_stays_hot_only():
     assert pipe.query.status()["cold_scans"] == 0
 
 
+def test_cold_query_refuses_session_windows():
+    """A cold scan reads the range widened by the spec's size, which
+    bounds no session: a session spec is refused, not answered with
+    sessions cut at the range's edges."""
+    from repro.query.engine import QueryEngine
+    store = MaterializedStore(max_windows_per_key=1)
+    store.on_advance([WindowAggregate("a", 0.0, 40.0, count=3),
+                      WindowAggregate("a", 100.0, 110.0, count=1)], 200.0)
+    assert store.floor == 40.0
+    eng = QueryEngine(store, spec=WindowSpec(kind="session", gap_s=10.0),
+                      log=object())
+    with pytest.raises(ValueError, match="session windows"):
+        eng.query(AggQuery(channel="a", start=0.0, end=200.0))
+    # a range the hot store still holds needs no cold scan
+    res = eng.query(AggQuery(channel="a", start=50.0, end=200.0))
+    assert res.source == "hot" and eng.status()["cold_scans"] == 0
+
+
 # ---------------------------------------------------------------------------
 # replayed late events merge into serving state (export hook from replay)
 # ---------------------------------------------------------------------------
