@@ -22,13 +22,17 @@ from types import SimpleNamespace
 import numpy as np
 
 from bench.drivers import build_pipeline
-from bench.drivers.replay import STAGES
+from bench.drivers import replay as replay_driver
 from bench.gen import log_data
 from bench.harness import Check, Outcome, annotate, holds
 from bench.refs.reduce import agg_lanes, control_reduce, reference_reduce
 from bench.refs.sessions import (compare_sessions, merge_session_readings,
                                  session_ends, sessionize)
 from bench.work import window_reduce_work
+
+# the replay stages, and the session pack's and the kernel's sub-stages
+STAGES = replay_driver.STAGES + ("pack_events.sessions", "pack_events.slots",
+                                 "kernel.wait")
 
 
 def run(ctx) -> Outcome:
@@ -83,7 +87,9 @@ def run(ctx) -> Outcome:
             "events_per_replay": data.count,
             "memberships": data.count, "slots": len(ref_ends),
             "replay_s": {"min": took[0], "median": took[len(took) // 2],
-                         "max": took[-1]}}
+                         "max": took[-1]},
+            "stage_s_per_replay": {k: v / len(ends)
+                                   for k, v in stages.items()}}
     if packs0 is not None:
         # the program's own counters over the window, per replay: one
         # session pack, and its sessions by what opened them
