@@ -26,8 +26,9 @@ from bench.drivers import replay as replay_driver
 from bench.gen import log_data
 from bench.harness import Check, Outcome, annotate, holds
 from bench.refs.reduce import agg_lanes, control_reduce, reference_reduce
-from bench.refs.sessions import (compare_sessions, merge_session_readings,
-                                 session_ends, sessionize)
+from bench.refs.sessions import (compare_sessions, control_ends,
+                                 merge_session_readings, session_ends,
+                                 sessionize)
 from bench.work import window_reduce_work
 
 # the replay stages, and the session pack's and the kernel's sub-stages
@@ -104,7 +105,8 @@ def run(ctx) -> Outcome:
         control = None
         if ctx.control:
             ctl = control_reduce(data.keys, starts, data.values)
-            control = compare_sessions(ctl, ref_ends, ref, ref_ends)
+            control = compare_sessions(ctl, control_ends(ref_ends, gap_s),
+                                       ref, ref_ends)
         return Check(readings=merge_session_readings(parts), failed=failed,
                      control=control)
 

@@ -9,12 +9,14 @@ event plus ``gap_s``.
 ``sessionize`` walks the events one by one in (key, time) order and
 gives each its session's start; the lanes then come from
 ``bench.refs.reduce``'s float64 group-by over (key, session start), and
-its bfloat16 control.  Nothing of the program is imported.
+its bfloat16 control, whose session ends ``control_ends`` computes in
+bfloat16 too.  Nothing of the program is imported.
 """
 from __future__ import annotations
 
 from typing import Dict, Sequence, Tuple
 
+import ml_dtypes
 import numpy as np
 
 from bench.refs import reduce
@@ -43,6 +45,14 @@ def sessionize(keys: np.ndarray, ts: np.ndarray, gap_s: float
     if key is not None:
         ends[(str(key), start)] = last + gap_s
     return starts, ends
+
+
+def control_ends(ends: Dict[Slot, float], gap_s: float) -> Dict[Slot, float]:
+    """The reference's session ends one precision below float32: each
+    session's last event plus the gap, added in bfloat16."""
+    bf16 = ml_dtypes.bfloat16
+    return {slot: float(bf16(end - gap_s) + bf16(gap_s))
+            for slot, end in ends.items()}
 
 
 def session_ends(aggs) -> Dict[Slot, float]:

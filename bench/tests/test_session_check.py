@@ -69,6 +69,7 @@ def test_session_cell_is_correct_and_its_control_is_not():
     assert r["correct"] and r["failed"] == 0 and r["attempted"] >= 1
     assert r["checks"]["session_end_mismatch"]["value"] == 0
     assert r["info"]["control_correct"] is False
+    assert r["info"]["control"]["session_end_mismatch"]["value"] > 0
     assert list(r)[-1] == "checks"
 
 
