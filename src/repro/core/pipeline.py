@@ -68,7 +68,8 @@ from repro.core.sinks import IndexSink
 from repro.core.sources import NOT_MODIFIED, SourceSimulator
 from repro.delivery import BatchingSink, FanOutSink, RetryingSink, as_sink
 from repro.obs import (LatencySink, Observability, TracingSink,
-                       kernel_launches, pack_sessions, pack_slot_index)
+                       columnar_blocks_decoded, kernel_launches,
+                       pack_sessions, pack_slot_index)
 
 # repro.ingest imports repro.core.registry (which runs this package's
 # __init__) — import it lazily to keep `import repro.ingest` first legal
@@ -1233,6 +1234,11 @@ class AlertMixPipeline:
             c("pack_sessions_total",
               "sessions laid out by session packs, by what opened "
               "them (a new key or a gap)").sync(n, cut=cut)
+        for layout, n in columnar_blocks_decoded().items():
+            c("columnar_blocks_decoded_total",
+              "columnar store blocks read and checksummed, by where "
+              "their string dictionaries lie (payload or header)").sync(
+                n, layout=layout)
         if self.query is not None:
             qs = self.query.status()
             c("query_queries_total",

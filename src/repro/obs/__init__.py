@@ -21,7 +21,9 @@ closes the loop the paper leaves to AWS:
                    beside it pack_slot_index, column packs per
                    slot-index path (dense, sort, session), and
                    pack_sessions, packed sessions per cut (key,
-                   gap)                           (launches.py)
+                   gap), and columnar_blocks_decoded, store blocks
+                   read per dictionary layout (payload,
+                   header)                        (launches.py)
   MetricsConnector self-monitoring: registry snapshots re-enter the
                    platform as an ordinary stream on a ``__health__``
                    channel, so the EXISTING rule engine alarms on the
@@ -48,8 +50,8 @@ from repro.obs.metrics import (
     MetricsRegistry,
 )
 from repro.obs.latency import LatencySink, LatencyTracker
-from repro.obs.launches import (kernel_launches, pack_sessions,
-                                pack_slot_index)
+from repro.obs.launches import (columnar_blocks_decoded, kernel_launches,
+                                pack_sessions, pack_slot_index)
 from repro.obs.profiler import StageProfiler, recent_passes
 from repro.obs.slo import SLOEngine, SLOSpec
 from repro.obs.trace import Span, TraceExporter, Tracer, TracingSink
@@ -79,5 +81,6 @@ __all__ = [
     "Counter", "Gauge", "Histogram", "LatencySink", "LatencyTracker",
     "MetricsRegistry", "Observability", "SLOEngine", "SLOSpec",
     "Span", "StageProfiler", "TraceExporter", "Tracer", "TracingSink",
-    "kernel_launches", "pack_sessions", "pack_slot_index", "recent_passes",
+    "columnar_blocks_decoded", "kernel_launches", "pack_sessions",
+    "pack_slot_index", "recent_passes",
 ]

@@ -21,6 +21,10 @@ key's range), ``sort`` (a sort of the keys) or ``session`` (the session
 layout: a sort by key and event time, cut at idle gaps), and
 ``pack_sessions()`` counts the sessions those session packs laid out by
 what opened each: a new ``key`` or a ``gap`` longer than the spec's.
+``columnar_blocks_decoded()`` counts the columnar store's blocks read
+and checksummed (``repro.store.columnar.blocks.iter_blocks``) by where
+each keeps its string dictionaries: in the checksummed ``payload``, or
+in the json ``header`` as blocks written before that did.
 
 The counters are process-wide, as the kernels' compiled executables
 are, and never import JAX, so the metrics collector can read them from
@@ -112,3 +116,20 @@ def pack_sessions() -> Dict[str, int]:
     """Snapshot of the process's packed sessions per cut."""
     with _pack_lock:
         return dict(_session_cuts)
+
+
+BLOCK_LAYOUTS = ("payload", "header")
+_block_layouts: Dict[str, int] = dict.fromkeys(BLOCK_LAYOUTS, 0)
+
+
+def record_columnar_block(layout: str) -> None:
+    """Count one columnar block decoded whose dictionaries lie in
+    ``layout``, one of ``BLOCK_LAYOUTS``."""
+    with _pack_lock:
+        _block_layouts[layout] += 1
+
+
+def columnar_blocks_decoded() -> Dict[str, int]:
+    """Snapshot of the process's decoded columnar blocks per layout."""
+    with _pack_lock:
+        return dict(_block_layouts)

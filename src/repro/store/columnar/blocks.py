@@ -6,14 +6,29 @@ A ``.colb`` file is a sequence of self-describing blocks:
     | MAGIC | u32 hlen   | header json  | payload (hlen..) |
     +-------+------------+--------------+------------------+
 
+``MAGIC`` is ``ACB2``.
 The header carries the row count, the offset range, a CRC32 over the
 payload bytes, block stats (min/max event-time and key range — the
-basis for pruned scans), and per-column descriptors.  Column kinds:
+basis for pruned scans), and per-column descriptors: each column's
+kind and where its bytes lie in the payload.  Every column's data,
+string dictionaries included, lies in the payload, so the CRC covers
+it and the header stays small.  Column kinds:
 
     f8 / i8   little-endian float64 / int64 lanes (numpy-decodable)
-    u4dict    u32 codes into a per-block vocabulary (strings)
-    str       u32 lengths + concatenated utf-8 bytes
+    dict      u32 codes into a per-block dictionary of packed strings,
+              which follows the codes in the payload (descriptor
+              ``voff``: where, ``vn``: how many)
+    str       packed strings: u32 lengths + concatenated utf-8 bytes
     json      one json array for columns that resist a typed lane
+
+Blocks written before dictionaries moved into the payload begin with
+``ACB1`` (``LEGACY_MAGIC``) and carry each dict column's dictionary in
+its descriptor as a json list (``vocab``, outside the CRC).  They still
+decode, read that way by their magic, so old and new segments mix in one
+log; a reader that knows only ``ACB1`` refuses an ``ACB2`` block as a
+bad magic.  A dictionary
+is decoded only when its column is asked for; ``lane_key_bytes()``
+gives the key lane's dictionary as bytes, with no string decoded.
 
 Partially-present columns carry a u8 presence mask.  Two reserved
 lanes are always written: ``_off`` (the record offsets — compaction
@@ -32,7 +47,10 @@ from typing import Callable, Iterable, Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-MAGIC = b"ACB1"
+from repro.obs.launches import record_columnar_block
+
+MAGIC = b"ACB2"
+LEGACY_MAGIC = b"ACB1"        # dictionaries as json in the header
 
 Record = Tuple[int, object]          # (offset, payload)
 
@@ -44,6 +62,35 @@ class CorruptBlockError(Exception):
 def default_key(doc: dict) -> str:
     """The pipeline's aggregation key — mirrors AnalyticsStage."""
     return str(doc.get("key", doc.get("channel", "all")))
+
+
+def _utf8(values: Sequence[str]) -> List[bytes]:
+    """utf-8 bytes; ``surrogatepass`` lets every Python string round-trip,
+    lone surrogates included."""
+    return [v.encode("utf-8", "surrogatepass") for v in values]
+
+
+def _packed(enc: Sequence[bytes]) -> bytes:
+    """Packed strings: u32 lengths, then the concatenated bytes."""
+    return (np.array([len(b) for b in enc], dtype="<u4").tobytes()
+            + b"".join(enc))
+
+
+def _packed_bytes(buf, off: int, n: int) -> List[bytes]:
+    """The ``n`` packed strings at ``off`` in ``buf``, as bytes."""
+    ends = np.cumsum(np.frombuffer(buf, dtype="<u4", count=n, offset=off),
+                     dtype=np.int64).tolist()
+    base = off + 4 * n
+    blob = bytes(buf[base:base + (ends[-1] if n else 0)])
+    return [blob[s:e] for s, e in zip([0] + ends[:-1], ends)]
+
+
+def decode_utf8(entries: List[bytes]) -> List[str]:
+    """utf-8 bytes back to strings, as ``_utf8`` wrote them."""
+    try:
+        return list(map(bytes.decode, entries))
+    except UnicodeDecodeError:          # a lone surrogate
+        return [b.decode("utf-8", "surrogatepass") for b in entries]
 
 
 def _classify(values: Sequence[object]) -> str:
@@ -110,15 +157,18 @@ def encode_block(records: Sequence[Record], *,
     cols: List[dict] = []
 
     def emit(name: str, kind: str, data: bytes, *,
-             mask: Optional[np.ndarray] = None, extra: dict = None):
+             mask: Optional[np.ndarray] = None,
+             vocab: Optional[List[str]] = None):
         desc = {"name": name, "kind": kind, "off": len(payload),
                 "n": len(data)}
         payload.extend(data)
         if mask is not None and int(mask.sum()) != rows:
             desc["mask"] = len(payload)
             payload.extend(mask.tobytes())
-        if extra:
-            desc.update(extra)
+        if vocab is not None:
+            desc["voff"] = len(payload)
+            desc["vn"] = len(vocab)
+            payload.extend(_packed(_utf8(vocab)))
         cols.append(desc)
 
     def emit_dict(name: str, values: Sequence[str],
@@ -132,8 +182,7 @@ def encode_block(records: Sequence[Record], *,
                 c = index[s] = len(vocab)
                 vocab.append(s)
             codes[i] = c
-        emit(name, "dict", codes.tobytes(), mask=mask,
-             extra={"vocab": vocab})
+        emit(name, "dict", codes.tobytes(), mask=mask, vocab=vocab)
 
     emit("_off", "i8", offs.tobytes())
     emit_dict("_key", keys, None)
@@ -142,11 +191,9 @@ def encode_block(records: Sequence[Record], *,
              json.dumps(raws, separators=(",", ":")).encode("utf-8"),
              mask=raw_mask)
     if any(i is not None for i in ids):
-        id_vals = ["" if s is None else s for s in ids]
-        lens = np.array([len(s.encode("utf-8")) for s in id_vals],
-                        dtype="<u4")
-        data = lens.tobytes() + "".join(id_vals).encode("utf-8")
-        emit("id", "str", data, mask=1 - raw_mask)
+        emit("id", "str", _packed(_utf8(["" if s is None else s
+                                          for s in ids])),
+             mask=1 - raw_mask)
 
     for name in sorted(fields):
         values, mask = fields[name]
@@ -192,10 +239,14 @@ def encode_block(records: Sequence[Record], *,
 class Block:
     """One decoded (or header-only) block."""
 
-    __slots__ = ("header", "_payload", "_cols")
+    __slots__ = ("header", "layout", "_payload", "_cols")
 
-    def __init__(self, header: dict, payload: Optional[bytes]):
+    def __init__(self, header: dict, payload: Optional[bytes],
+                 layout: str = "payload"):
         self.header = header
+        # where the block keeps its dictionaries: ``payload`` or, for
+        # blocks written before they moved there, ``header``
+        self.layout = layout
         self._payload = payload
         self._cols = {c["name"]: c for c in header["cols"]}
 
@@ -236,21 +287,22 @@ class Block:
                                 offset=off)
             return kind, arr, mask
         if kind == "dict":
-            codes = np.frombuffer(self._payload, dtype="<u4",
-                                  count=self.rows, offset=off)
-            return kind, (codes, desc["vocab"]), mask
+            vocab = desc["vocab"] if self.layout == "header" else \
+                decode_utf8(self._dictionary_bytes(desc))
+            return kind, (self._codes(desc), vocab), mask
         if kind == "str":
-            lens = np.frombuffer(self._payload, dtype="<u4",
-                                 count=self.rows, offset=off)
-            raw = bytes(self._payload[off + 4 * self.rows: off + n])
-            ends = np.cumsum(lens)
-            starts = ends - lens
-            vals = [raw[s:e].decode("utf-8")
-                    for s, e in zip(starts.tolist(), ends.tolist())]
-            return kind, vals, mask
+            return kind, decode_utf8(_packed_bytes(self._payload, off,
+                                                   self.rows)), mask
         # json
         vals = json.loads(bytes(self._payload[off:off + n]).decode("utf-8"))
         return kind, vals, mask
+
+    def _dictionary_bytes(self, desc: dict) -> List[bytes]:
+        return _packed_bytes(self._payload, desc["voff"], desc["vn"])
+
+    def _codes(self, desc: dict) -> np.ndarray:
+        return np.frombuffer(self._payload, dtype="<u4", count=self.rows,
+                             offset=desc["off"])
 
     def offsets(self) -> np.ndarray:
         return self.column("_off")[1]
@@ -292,6 +344,14 @@ class Block:
         """Aggregation-key lane: (u32 codes, vocab)."""
         _, (codes, vocab), _ = self.column("_key")
         return codes, vocab
+
+    def lane_key_bytes(self) -> Tuple[np.ndarray, List[bytes]]:
+        """Aggregation-key lane with its dictionary as utf-8 bytes:
+        (u32 codes, entries), no string decoded."""
+        desc = self._cols["_key"]
+        entries = _utf8(desc["vocab"]) if self.layout == "header" else \
+            self._dictionary_bytes(desc)
+        return self._codes(desc), entries
 
     def ids(self) -> List[Optional[str]]:
         """doc_id per row (None for raw rows) — the compaction key."""
@@ -339,20 +399,30 @@ class Block:
         return out
 
 
+def _layout(data: bytes, pos: int) -> str:
+    """The dictionary layout of the block at ``pos``, from its magic."""
+    magic = data[pos:pos + 4]
+    if magic == MAGIC:
+        return "payload"
+    if magic == LEGACY_MAGIC:
+        return "header"
+    raise CorruptBlockError(f"bad block magic at byte {pos}")
+
+
 def iter_blocks(data: bytes, *, want=None,
                 verify: bool = True) -> Iterator[Block]:
     """Iterate blocks in ``data``.  ``want(header) -> bool`` prunes a
     block before its payload is touched or checksummed — pruned blocks
-    are skipped entirely (the caller counts them from the headers)."""
+    are skipped entirely (the caller counts them from the headers).  A
+    block's payload is a view into ``data``, not a copy."""
     pos, n = 0, len(data)
+    view = memoryview(data)
     while pos < n:
-        if data[pos:pos + 4] != MAGIC:
-            raise CorruptBlockError(
-                f"bad block magic at byte {pos}")
+        layout = _layout(data, pos)
         hlen = int.from_bytes(data[pos + 4:pos + 8], "little")
         hstart = pos + 8
         try:
-            header = json.loads(data[hstart:hstart + hlen].decode("utf-8"))
+            header = json.loads(data[hstart:hstart + hlen])
         except Exception as e:
             raise CorruptBlockError(f"bad block header at byte {pos}: {e}")
         pstart = hstart + hlen
@@ -361,12 +431,14 @@ def iter_blocks(data: bytes, *, want=None,
             raise CorruptBlockError(
                 f"truncated block payload at byte {pos}")
         if want is None or want(header):
-            payload = data[pstart:pend]
+            payload = view[pstart:pend]
             if verify and zlib.crc32(payload) != header["crc"]:
                 raise CorruptBlockError(
                     f"block checksum mismatch at byte {pos} "
                     f"(offsets {header['first']}..{header['last']})")
-            yield Block(header, payload)
+            blk = Block(header, payload, layout)
+            record_columnar_block(layout)
+            yield blk
         pos = pend
 
 
@@ -384,10 +456,9 @@ def file_stats(data: bytes) -> dict:
     rows, min_ts, max_ts = 0, None, None
     pos, n = 0, len(data)
     while pos < n:
-        if data[pos:pos + 4] != MAGIC:
-            raise CorruptBlockError(f"bad block magic at byte {pos}")
+        _layout(data, pos)
         hlen = int.from_bytes(data[pos + 4:pos + 8], "little")
-        header = json.loads(data[pos + 8:pos + 8 + hlen].decode("utf-8"))
+        header = json.loads(data[pos + 8:pos + 8 + hlen])
         rows += header["rows"]
         st = header["stats"]
         if st["min_ts"] is not None:

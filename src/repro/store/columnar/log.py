@@ -31,14 +31,15 @@ import os
 import re
 import zlib
 from dataclasses import dataclass
+from itertools import count
 from typing import Iterator, List, Optional, Sequence, Set, Tuple
 
 import numpy as np
 
 from ..segment_log import (EventLog, Segment, CorruptSegmentError,
                            MANIFEST, _decode)
-from .blocks import (Block, CorruptBlockError, default_key, encode_file,
-                     file_stats, iter_blocks)
+from .blocks import (Block, CorruptBlockError, decode_utf8, default_key,
+                     encode_file, file_stats, iter_blocks)
 from .tiering import ObjectStore, ObjectStoreError
 
 _COLB_RE = re.compile(r"^seg-(\d{12})(?:\.g(\d+))?\.colb$")
@@ -66,6 +67,29 @@ class Lanes:
 def _empty_lanes() -> Lanes:
     return Lanes(ts=np.empty(0), key_codes=np.empty(0, dtype=np.int64),
                  key_vocab=[], values=np.empty(0))
+
+
+def _merge_lanes(entries: List[bytes], ts_parts: List[np.ndarray],
+                 entry_parts: List[np.ndarray],
+                 val_parts: List[np.ndarray]) -> Lanes:
+    """One vocabulary from the scanned key entries (utf-8 bytes), codes
+    in the order the keys were first seen, and the lanes concatenated.
+    One dict operation per entry: ``seen`` is the index of the entry's
+    first sighting, and those indices rise in the order the keys were
+    first seen, so ranking them gives the codes.  Only the distinct keys
+    are decoded to ``str``."""
+    if not ts_parts:
+        return _empty_lanes()
+    first: dict = {}
+    seen = np.fromiter(map(first.setdefault, entries, count()),
+                       dtype=np.int64, count=len(entries))
+    code_of = np.empty(len(entries), dtype=np.int64)
+    code_of[np.fromiter(first.values(), dtype=np.int64,
+                        count=len(first))] = np.arange(len(first))
+    return Lanes(ts=np.concatenate(ts_parts),
+                 key_codes=code_of[seen][np.concatenate(entry_parts)],
+                 key_vocab=decode_utf8(list(first)),
+                 values=np.concatenate(val_parts))
 
 
 class ColumnarEventLog(EventLog):
@@ -474,7 +498,8 @@ class ColumnarEventLog(EventLog):
                    ts_min: Optional[float] = None,
                    ts_max: Optional[float] = None,
                    keys: Optional[Sequence[str]] = None,
-                   include_tail: bool = True) -> Lanes:
+                   include_tail: bool = True,
+                   profiler=None) -> Lanes:
         """Gather ts/key/value lanes across the whole log: sealed
         columnar segments decode as numpy arrays (zero per-record
         Python); the JSON tail (and any legacy sealed JSONL) is
@@ -484,90 +509,112 @@ class ColumnarEventLog(EventLog):
         ``key = doc.get("key", doc.get("channel", "all"))``,
         ``value = doc.get("value", 1.0)``, ``ts = doc["published_at"]``;
         rows without a numeric event time (and non-document payloads)
-        are dropped, exactly as the live path would reject them."""
+        are dropped, exactly as the live path would reject them.
+
+        The blocks' key dictionaries merge on their utf-8 bytes: codes
+        follow each key's first sighting (blocks in log order, each
+        block's dictionary in its order, then the tail's rows), and only
+        the distinct keys are decoded to ``str``.
+
+        ``profiler`` (a ``StageProfiler``, the replay's) times the scan
+        in two passes: ``decode.blocks`` (files, headers, checksums, the
+        ts, value and key-code lanes) then ``decode.keys`` (the merge of
+        the key dictionaries, the remap and the final concatenation)."""
+        def stage(name: str):
+            return (contextlib.nullcontext() if profiler is None
+                    else profiler.stage(name))
+
         keyset = None if keys is None else set(keys)
-        vocab: List[str] = []
-        vindex: dict = {}
+        entries: List[bytes] = []       # kept blocks' key dictionaries
         ts_parts: List[np.ndarray] = []
-        code_parts: List[np.ndarray] = []
+        entry_parts: List[np.ndarray] = []   # each row's index in entries
         val_parts: List[np.ndarray] = []
+        with stage("decode.blocks"):
+            keybytes = None if keyset is None else {
+                k.encode("utf-8", "surrogatepass") for k in keyset}
+            for blk in self.scan_columns(from_offset, ts_min=ts_min,
+                                         ts_max=ts_max, keys=keys):
+                bts = blk.lane_ts()
+                mask = ~np.isnan(bts)
+                if from_offset > blk.first:
+                    mask &= blk.offsets() >= from_offset
+                if ts_min is not None:
+                    mask &= bts >= ts_min
+                if ts_max is not None:
+                    mask &= bts < ts_max
+                codes, bentries = blk.lane_key_bytes()
+                if keybytes is not None:
+                    allowed = np.fromiter(map(keybytes.__contains__,
+                                              bentries),
+                                          dtype=bool, count=len(bentries))
+                    mask &= allowed[codes]
+                bvals = blk.lane_value()
+                if not mask.all():
+                    if not mask.any():
+                        continue
+                    bts, codes, bvals = bts[mask], codes[mask], bvals[mask]
+                ts_parts.append(bts)
+                entry_parts.append(np.add(codes, len(entries),
+                                          dtype=np.int64))
+                val_parts.append(bvals)
+                entries.extend(bentries)
+            if include_tail:
+                self._tail_lanes(from_offset, ts_min, ts_max, keyset,
+                                 entries, ts_parts, entry_parts, val_parts)
+        with stage("decode.keys"):
+            lanes = _merge_lanes(entries, ts_parts, entry_parts, val_parts)
+            # the parts hold views of the segments' bytes and one object
+            # per entry: free them inside the stage that used them last
+            del entries, ts_parts, entry_parts, val_parts
+        return lanes
 
-        def intern(key: str) -> int:
-            c = vindex.get(key)
-            if c is None:
-                c = vindex[key] = len(vocab)
-                vocab.append(key)
-            return c
-
-        for blk in self.scan_columns(from_offset, ts_min=ts_min,
-                                     ts_max=ts_max, keys=keys):
-            bts = blk.lane_ts()
-            mask = ~np.isnan(bts)
-            if from_offset > blk.first:
-                mask &= blk.offsets() >= from_offset
-            if ts_min is not None:
-                mask &= bts >= ts_min
-            if ts_max is not None:
-                mask &= bts < ts_max
-            codes, bvocab = blk.lane_key()
-            if keyset is not None:
-                allowed = np.array([s in keyset for s in bvocab],
-                                   dtype=bool)
-                mask &= allowed[codes]
-            if not mask.any():
-                continue
-            remap = np.array([intern(s) for s in bvocab], dtype=np.int64)
-            ts_parts.append(bts[mask])
-            code_parts.append(remap[codes[mask]])
-            val_parts.append(blk.lane_value()[mask])
-        if include_tail:
-            with self._lock:
-                sealed = list(self._sealed)
-                active = self._active_name
-                if self._fh is not None:
-                    self._fh.flush()
-            tail_rows: List[Tuple[float, int, float]] = []
-            names = [s.name for s in sealed
-                     if s.last >= from_offset
-                     and not s.name.endswith(".colb")]
-            if active is not None:
-                names.append(active)
-            for name in names:
-                recs, _ = self._scan_file(name)
-                for off, payload in recs:
-                    if off < from_offset:
-                        continue
-                    if not (isinstance(payload, dict)
-                            and isinstance(payload.get("doc"), dict)):
-                        continue
-                    doc = payload["doc"]
-                    ts = doc.get("published_at")
-                    if isinstance(ts, bool) or \
-                            not isinstance(ts, (int, float)):
-                        continue
-                    ts = float(ts)
-                    if ts_min is not None and ts < ts_min:
-                        continue
-                    if ts_max is not None and ts >= ts_max:
-                        continue
-                    key = default_key(doc)
-                    if keyset is not None and key not in keyset:
-                        continue
-                    v = doc.get("value", 1.0)
-                    v = float(v) if isinstance(v, (int, float)) \
-                        and not isinstance(v, bool) else 1.0
-                    tail_rows.append((ts, intern(key), v))
-            if tail_rows:
-                arr = np.array(tail_rows, dtype=np.float64)
-                ts_parts.append(arr[:, 0])
-                code_parts.append(arr[:, 1].astype(np.int64))
-                val_parts.append(arr[:, 2])
-        if not ts_parts:
-            return _empty_lanes()
-        return Lanes(ts=np.concatenate(ts_parts),
-                     key_codes=np.concatenate(code_parts),
-                     key_vocab=vocab,
-                     values=np.concatenate(val_parts))
+    def _tail_lanes(self, from_offset: int, ts_min: Optional[float],
+                    ts_max: Optional[float], keyset: Optional[Set[str]],
+                    entries: List[bytes], ts_parts: List[np.ndarray],
+                    entry_parts: List[np.ndarray],
+                    val_parts: List[np.ndarray]) -> None:
+        """``scan_lanes``' rows of the JSON tail and of any legacy sealed
+        JSONL, appended to its parts; each row's key is one more entry."""
+        with self._lock:
+            sealed = list(self._sealed)
+            active = self._active_name
+            if self._fh is not None:
+                self._fh.flush()
+        tail_rows: List[Tuple[float, int, float]] = []
+        names = [s.name for s in sealed
+                 if s.last >= from_offset and not s.name.endswith(".colb")]
+        if active is not None:
+            names.append(active)
+        for name in names:
+            recs, _ = self._scan_file(name)
+            for off, payload in recs:
+                if off < from_offset:
+                    continue
+                if not (isinstance(payload, dict)
+                        and isinstance(payload.get("doc"), dict)):
+                    continue
+                doc = payload["doc"]
+                ts = doc.get("published_at")
+                if isinstance(ts, bool) or not isinstance(ts, (int, float)):
+                    continue
+                ts = float(ts)
+                if ts_min is not None and ts < ts_min:
+                    continue
+                if ts_max is not None and ts >= ts_max:
+                    continue
+                key = default_key(doc)
+                if keyset is not None and key not in keyset:
+                    continue
+                v = doc.get("value", 1.0)
+                v = float(v) if isinstance(v, (int, float)) \
+                    and not isinstance(v, bool) else 1.0
+                tail_rows.append((ts, len(entries), v))
+                entries.append(key.encode("utf-8", "surrogatepass"))
+        if tail_rows:
+            arr = np.array(tail_rows, dtype=np.float64)
+            ts_parts.append(arr[:, 0])
+            entry_parts.append(arr[:, 1].astype(np.int64))
+            val_parts.append(arr[:, 2])
 
     # ---- keyed compaction (keep-last-per-doc-id) ----------------------------
     def _compact_plan(self) -> Optional[dict]:

@@ -261,7 +261,8 @@ class ReplayEngine:
             else hasattr(self.log, "scan_lanes"))
         if use and hasattr(self.log, "scan_lanes"):
             with self.profiler.stage("decode"):   # columnar block scan
-                lanes = self.log.scan_lanes(from_offset)
+                lanes = self.log.scan_lanes(from_offset,
+                                            profiler=self.profiler)
             last = self.log.next_offset - 1
             aggs, fired = self.replay_columns(lanes, watermark=watermark)
             return {"events": lanes.count, "aggregates": len(aggs),
