@@ -144,10 +144,11 @@ def bench_concurrency(num_sources: int, virtual_s: float,
 
 def bench_cold_range(num_sources: int, virtual_s: float,
                      iters: int, *, columnar: bool = False) -> dict:
-    """Queries below the retention floor: EventLog scan + kernel path,
-    with parity vs a pure-Python fold of the same log asserted.  With
-    ``columnar=True`` the store is a ColumnarEventLog and the cold scan
-    rides block-stat-pruned numpy lanes instead of per-record decode."""
+    """Queries below the retention floor: the log's ``scan_lanes`` +
+    kernel path, with parity vs a pure-Python fold of the same log
+    asserted.  With ``columnar=True`` the store is a ColumnarEventLog,
+    whose scan decodes block-stat-pruned numpy lanes; the JSON EventLog
+    reads its records row by row into the same lanes."""
     p, d = _drive(num_sources, virtual_s, store=True, retention=16,
                   columnar=columnar)
     try:

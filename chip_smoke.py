@@ -23,8 +23,9 @@ kernel path:
                    of the events the live window operator dead-lettered;
   (c) cold_query   ``AggQuery(agg="min")`` over ``[0, floor)`` for each
                    channel, against a pure-Python fold of the event log;
-  (b) log_replay   ``ReplayEngine.replay_columns`` over the whole sealed
-                   columnar log, against a float64 group-by of its lanes
+  (b) log_replay   ``ReplayEngine.replay_columns`` over the whole
+                   columnar log (sealed blocks and JSON tail), against a
+                   float64 group-by of its lanes
                    (run after (c): replayed windows also merge into the
                    query plane's hot store);
   (d) direct       one ``ops.window_reduce`` launch at 200,000 events x
@@ -381,9 +382,10 @@ def check_cold_query(p) -> dict:
 
 
 def check_log_replay(p) -> dict:
-    """(b): ``replay_columns`` over the whole sealed columnar log."""
-    lanes = p.store.log.scan_lanes(include_tail=False)
-    _check(lanes.count > 0, "no sealed columnar segment to replay")
+    """(b): ``replay_columns`` over the whole columnar log."""
+    _check(p.store_stats()["columnar"]["sealed_columnar_segments"] > 0,
+           "no sealed columnar segment to replay")
+    lanes = p.store.log.scan_lanes()
     aggs, fired = p.store.replay.replay_columns(lanes, watermark=p.now)
     keys = [lanes.key_vocab[c] for c in lanes.key_codes]
     ref = reference_reduce(keys, tumbling_starts(lanes.ts, WINDOW_S),

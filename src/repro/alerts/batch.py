@@ -1,24 +1,29 @@
 """Bridge between host-side windowing and the Pallas segment reduction.
 
-``pack_events`` flattens (key, event_time, value) triples into the flat
-``values`` / ``seg_ids`` tensors ``repro.kernels.ops.window_reduce``
-consumes (one segment per distinct (key, window) slot — sliding windows
-replicate an event into every covering slot, and session windows make
-one slot per session the batch's events form), and ``reduce_events``
-turns the kernel's (S, 4) count/sum/sumsq/max lanes back into
-``WindowAggregate`` records.  This is the batch/replay path — reprocessing
-a backlog of documents at hardware speed — complementing the incremental
+Every batch route — log replay, the late-event drain, cold-range
+queries — reaches the kernel through one path: column lanes (event
+times, key codes into a vocabulary, values) -> ``pack_columns``, which
+flattens them into the flat ``values`` / ``seg_ids`` tensors
+``repro.kernels.ops.window_reduce`` consumes (one segment per distinct
+(key, window) slot — sliding windows replicate an event into every
+covering slot, and session windows make one slot per session the
+batch's events form) -> the kernel -> ``reduce_columns``' unpack of its
+(S, 4) count/sum/sumsq/max lanes into ``WindowAggregate`` records.
+(key, event_time, value) triples enter the same path once their keys
+are coded (``code_events``; ``pack_events`` and ``reduce_events`` are
+its fronts).  This is the batch/replay path — reprocessing a backlog of
+documents at hardware speed — complementing the incremental
 ``WindowOperator`` used on the live path; both produce identical
 aggregates (tested), so rules don't care which path fed them.
 """
 from __future__ import annotations
 
-from typing import Dict, List, Sequence, Tuple
+from typing import List, Sequence, Tuple
 
 import numpy as np
 
-from repro.alerts.windows import (SESSION, SLIDING, TUMBLING,
-                                  WindowAggregate, WindowSpec)
+from repro.alerts.windows import (SESSION, TUMBLING, WindowAggregate,
+                                  WindowSpec)
 from repro.obs.launches import record_pack_sessions, record_pack_slot_index
 
 Event = Tuple[str, float, float]          # (key, event_time, value)
@@ -31,44 +36,39 @@ Slot = Tuple[str, float, float]           # (key, window_start, window_end)
 _DENSE_KEYS_PER_MEMBERSHIP = 4
 
 
+def code_events(events: Sequence[Event]):
+    """(key, event_time, value) triples -> the column arrays
+    ``reduce_columns`` takes: (ts f64, key codes i64, values f64, vocab),
+    the keys coded in sorted order."""
+    vocab, codes = np.unique(np.asarray([e[0] for e in events], object),
+                             return_inverse=True)
+    return (np.asarray([e[1] for e in events], np.float64),
+            codes.astype(np.int64),
+            np.asarray([e[2] for e in events], np.float64), vocab.tolist())
+
+
 def pack_events(events: Sequence[Event], spec: WindowSpec):
     """-> (values f32 (N,), seg_ids i32 (N,), slots list[Slot]).
 
-    N >= len(events): sliding windows fan each event out to every slot
-    covering it.  Session windows take ``pack_columns``' session layout,
-    over the keys coded in sorted order, so slots come in (start, key)
-    order."""
-    if spec.kind == SESSION:
-        vocab, codes = np.unique(np.asarray([e[0] for e in events], object),
-                                 return_inverse=True)
-        packed, seg_ids, slots = pack_columns(
-            np.asarray([e[1] for e in events], np.float64), codes,
-            np.asarray([e[2] for e in events], np.float64), spec)
-        return packed, seg_ids, [(vocab[c], s, e) for c, s, e in slots]
-    slot_ids: Dict[Slot, int] = {}
-    vals: List[float] = []
-    segs: List[int] = []
-    for key, t, v in events:
-        for start, end in spec.assign(t):
-            slot = (key, start, end)
-            sid = slot_ids.setdefault(slot, len(slot_ids))
-            vals.append(v)
-            segs.append(sid)
-    slots = [s for s, _ in sorted(slot_ids.items(), key=lambda kv: kv[1])]
-    return (np.asarray(vals, np.float32), np.asarray(segs, np.int32), slots)
+    ``pack_columns`` over the events' coded keys (``code_events``), with
+    each slot's code mapped back to its key, so slots come in (start,
+    key) order for every window kind."""
+    ts, codes, values, vocab = code_events(events)
+    packed, seg_ids, slots = pack_columns(ts, codes, values, spec)
+    return packed, seg_ids, [(vocab[c], s, e) for c, s, e in slots]
 
 
 def pack_columns(ts: np.ndarray, key_codes: np.ndarray,
                  values: np.ndarray, spec: WindowSpec, *, profiler=None):
-    """Vectorized ``pack_events`` over COLUMN arrays (the columnar
-    store's ``scan_lanes`` output): no per-event Python at all.
+    """Pack COLUMN arrays (a log's ``scan_lanes`` output) for the
+    kernel: no per-event Python at all.
 
     -> (values f32 (N,), seg_ids i32 (N,), slots list[(key_code,
     start, end)]).  Window starts replicate ``WindowSpec.assign``'s
     exact float arithmetic (tumbling: one floor-multiply; sliding: the
-    same repeated subtraction, vectorized per step) so slots from the
-    two packers are bit-identical — the hot/cold dedup in the query
-    plane depends on it.
+    same repeated subtraction, vectorized per step) so slots are
+    bit-identical to the live operator's — the hot/cold dedup in the
+    query plane depends on it.
 
     Slots come in (start, key code) order, each membership's seg id is
     its slot's index in that order.  The slot index is built on one
@@ -209,23 +209,10 @@ def reduce_columns(ts: np.ndarray, key_codes: np.ndarray,
                    spec: WindowSpec, *, interpret=None, profiler=None,
                    with_min: bool = False,
                    route: str = "direct") -> List[WindowAggregate]:
-    """``reduce_events`` fed by column arrays: pack_columns ->
-    window_reduce -> WindowAggregates, with the same profiler stage
-    names so the replay breakdown stays comparable.  Per-record Python
-    appears only in the final per-SLOT unpack (S slots, not N events)."""
-    stage = _stage_of(profiler)
-    with stage("pack_events"):
-        packed_vals, seg_ids, slots = pack_columns(
-            ts, key_codes, values, spec, profiler=profiler)
-    return _reduce_packed(packed_vals, seg_ids, slots, key_vocab,
-                          stage=stage, interpret=interpret,
-                          with_min=with_min, route=route)
-
-
-def reduce_events(events: Sequence[Event], spec: WindowSpec, *,
-                  interpret=None, profiler=None, with_min: bool = False,
-                  route: str = "direct") -> List[WindowAggregate]:
-    """One kernel launch -> WindowAggregates for every touched slot.
+    """One kernel launch -> WindowAggregates for every touched slot:
+    pack_columns -> window_reduce -> unpack.  Per-record Python appears
+    only in the final per-SLOT unpack (S slots, not N events); a slot's
+    key is ``key_vocab[code]``.
 
     ``profiler`` (a ``repro.obs.StageProfiler``) itemizes the chain into
     pack_events / kernel / unpack stages, the kernel stage into
@@ -239,36 +226,26 @@ def reduce_events(events: Sequence[Event], spec: WindowSpec, *,
     already tracks it incrementally."""
     stage = _stage_of(profiler)
     with stage("pack_events"):
-        values, seg_ids, slots = pack_events(events, spec)
-    return _reduce_packed(values, seg_ids, slots, None, stage=stage,
-                          interpret=interpret, with_min=with_min,
-                          route=route)
-
-
-def _reduce_packed(values, seg_ids, slots, key_vocab, *, stage, interpret,
-                   with_min: bool, route: str) -> List[WindowAggregate]:
-    """The kernel and unpack stages both packers share.  A slot's key is
-    ``key_vocab[key]`` when a vocabulary is given (column packs carry
-    key codes), else the key itself."""
+        packed, seg_ids, slots = pack_columns(
+            ts, key_codes, values, spec, profiler=profiler)
     if not slots:
         return []
     with stage("kernel"):
         # the max lane is dispatched, waited on and fetched before the
         # min lane is dispatched
-        lanes = _kernel_lane(values, seg_ids, len(slots), stage=stage,
+        lanes = _kernel_lane(packed, seg_ids, len(slots), stage=stage,
                              interpret=interpret, route=route)
         mins = None
         if with_min:
-            mins = -_kernel_lane(values, seg_ids, len(slots), stage=stage,
+            mins = -_kernel_lane(packed, seg_ids, len(slots), stage=stage,
                                  interpret=interpret, route=route,
                                  negate=True)[:, 3]
     with stage("unpack"):
         out: List[WindowAggregate] = []
-        for sid, (key, start, end) in enumerate(slots):
+        for sid, (code, start, end) in enumerate(slots):
             cnt, sm, sq, mx = lanes[sid]
             agg = WindowAggregate(
-                key=key if key_vocab is None else key_vocab[key],
-                window_start=start, window_end=end,
+                key=key_vocab[code], window_start=start, window_end=end,
                 count=int(round(cnt)), sum=float(sm), sumsq=float(sq),
                 max=float(mx))
             if mins is not None:
@@ -276,6 +253,15 @@ def _reduce_packed(values, seg_ids, slots, key_vocab, *, stage, interpret,
             out.append(agg)
         out.sort(key=lambda a: (a.window_end, a.key))
     return out
+
+
+def reduce_events(events: Sequence[Event], spec: WindowSpec, *,
+                  interpret=None, profiler=None, with_min: bool = False,
+                  route: str = "direct") -> List[WindowAggregate]:
+    """``reduce_columns`` over (key, event_time, value) triples, their
+    keys coded by ``code_events``."""
+    return reduce_columns(*code_events(events), spec, interpret=interpret,
+                          profiler=profiler, with_min=with_min, route=route)
 
 
 def _kernel_lane(values, seg_ids, num_slots: int, *, stage, interpret,

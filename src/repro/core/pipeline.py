@@ -448,6 +448,7 @@ class AlertMixPipeline:
         if (cfg.analytics or cfg.query or analytics_rules is not None
                 or cfg.selfmon_interval_s is not None):
             from repro.alerts import AnalyticsStage, ThresholdRule, WindowSpec
+            from repro.store.lanes import doc_key
             if analytics_rules is not None:
                 rules = list(analytics_rules)
             elif cfg.analytics:
@@ -462,8 +463,7 @@ class AlertMixPipeline:
                 rules,
                 watermark_lag_s=cfg.watermark_lag_s,
                 dead_letters=self.dead_letters,
-                key_fn=lambda doc: str(doc.get("key",
-                                               doc.get("channel", "all"))),
+                key_fn=doc_key,
                 value_fn=lambda doc: float(doc.get("value", 1.0)),
                 alerts_keep_last=cfg.alerts_history)
             self.analytics.tracer = self.tracer
@@ -482,9 +482,7 @@ class AlertMixPipeline:
                 max_windows_per_key=cfg.query_max_windows_per_key,
                 clock=lambda: self.now,
                 dead_letters=self.dead_letters,
-                tracer=self.tracer if self.tracer.enabled else None,
-                columnar_lanes=(self.store is not None
-                                and self.store.columnar))
+                tracer=self.tracer if self.tracer.enabled else None)
         if self.store is not None:
             # the replay engine aggregates through the SAME rule-engine
             # state the live WindowOperator feeds (batch/live unification)

@@ -47,7 +47,7 @@ class QueryPlane:
                  cache_entries: int = 1024,
                  max_windows_per_key: int = 4096,
                  clock=None, dead_letters=None, tracer=None,
-                 interpret=None, columnar_lanes: bool = False):
+                 interpret=None):
         self.analytics = analytics
         self.store = MaterializedStore(
             max_windows_per_key=max_windows_per_key)
@@ -55,16 +55,12 @@ class QueryPlane:
             self.store,
             spec=analytics.operator.spec,
             log=log,
-            key_fn=analytics.key_fn,
-            value_fn=analytics.value_fn,
-            time_fn=analytics.time_fn,
             staleness_s=staleness_s,
             cache_entries=cache_entries,
             clock=clock,
             dead_letters=dead_letters,
             tracer=tracer,
-            interpret=interpret,
-            columnar_lanes=columnar_lanes)
+            interpret=interpret)
         analytics.add_export(self.store.on_advance)
 
     # ---- sync surface ------------------------------------------------------

@@ -16,8 +16,9 @@ it in three steps:
      identical dashboard queries cost one aggregation.
   3. *plan* — hot segments come from ``MaterializedStore.lookup`` with
      time/key pruning; if the range dips below the store's retention
-     floor and an EventLog is attached, the cold prefix is recomputed by
-     scanning the log and pushing the events through the same Pallas
+     floor and a document log is attached, the cold prefix is
+     recomputed from the log's ``scan_lanes`` (block-stat pruned on a
+     columnar log) through the same ``reduce_columns`` -> Pallas
      ``window_reduce`` batch path the replay engine uses.  Hot wins on
      overlap: a cold aggregate is only merged for slots the hot store
      no longer holds, so nothing double-counts.
@@ -145,20 +146,15 @@ class QueryEngine:
     def __init__(self, store: MaterializedStore, *,
                  spec=None,                      # WindowSpec (cold replay)
                  log=None,                       # repro.store EventLog
-                 key_fn=None, value_fn=None, time_fn=None,
                  staleness_s: Optional[float] = None,
                  cache_entries: int = 1024,
                  clock=None,
                  dead_letters=None,
                  tracer=None,
-                 interpret=None,
-                 columnar_lanes: bool = False):
+                 interpret=None):
         self.store = store
         self.spec = spec
         self.log = log
-        self.key_fn = key_fn or (lambda doc: str(doc.get("channel", "all")))
-        self.value_fn = value_fn or (lambda doc: 1.0)
-        self.time_fn = time_fn or (lambda doc: float(doc["published_at"]))
         self.staleness_s = staleness_s
         self.cache_entries = cache_entries
         # default clock = the serving watermark itself: standalone use
@@ -167,12 +163,6 @@ class QueryEngine:
         self.dead_letters = dead_letters
         self.tracer = tracer
         self.interpret = interpret
-        # columnar cold path: scan the log's column lanes (block-stat
-        # pruned, zero per-record Python) instead of per-record decode.
-        # Lane semantics equal the pipeline's DEFAULT extractors, so
-        # this must stay False when custom key/value/time fns are in
-        # play — the pipeline opts in when it mounts a columnar store.
-        self.columnar_lanes = columnar_lanes and hasattr(log, "scan_lanes")
         self._lock = threading.Lock()
         # query -> (watermark, version, QueryResult)
         self._cache: "OrderedDict[AggQuery, Tuple[float, int, QueryResult]]" \
@@ -278,8 +268,7 @@ class QueryEngine:
     def _cold_scan_inner(self, q: AggQuery, keys: Sequence[str],
                          hot: Dict[str, List[SegmentRow]]
                          ) -> Dict[str, List[SegmentRow]]:
-        from repro.alerts.batch import (reduce_columns,   # lazy: jax path
-                                        reduce_events)
+        from repro.alerts.batch import reduce_columns   # lazy: jax path
 
         cold_end = min(q.end, self.store.floor)
         # any window overlapping [q.start, cold_end) lies entirely within
@@ -287,43 +276,17 @@ class QueryEngine:
         # keeps boundary windows *complete* so their lanes match a full
         # recompute, then the slot filter below trims the overshoot
         slack = self.spec.size_s
-        keyset = set(keys)
-        if self.columnar_lanes:
-            # columnar route: block-stat-pruned lane scan, then the
-            # vectorized packer — no per-record Python anywhere
-            lanes = self.log.scan_lanes(ts_min=q.start - slack,
-                                        ts_max=cold_end + slack,
-                                        keys=keys)
-            self.stats["cold_scans"] += 1
-            self.stats["cold_events"] += lanes.count
-            self.stats["cold_columnar"] += 1
-            if lanes.count == 0:
-                return {}
-            aggs = reduce_columns(lanes.ts, lanes.key_codes, lanes.values,
-                                  lanes.key_vocab, self.spec,
-                                  interpret=self.interpret, with_min=True,
-                                  route="query")
-        else:
-            events = []
-            for _off, payload in self.log.scan():
-                doc = payload.get("doc", payload) \
-                    if isinstance(payload, dict) else payload
-                try:
-                    key = self.key_fn(doc)
-                    if key not in keyset:
-                        continue
-                    t = self.time_fn(doc)
-                except (AttributeError, KeyError, TypeError, ValueError):
-                    continue               # non-document payloads in the log
-                if q.start - slack <= t < cold_end + slack:
-                    events.append((key, t, self.value_fn(doc)))
-            self.stats["cold_scans"] += 1
-            self.stats["cold_events"] += len(events)
-            if not events:
-                return {}
-            aggs = reduce_events(events, self.spec,
-                                 interpret=self.interpret, with_min=True,
-                                 route="query")
+        lanes = self.log.scan_lanes(ts_min=q.start - slack,
+                                    ts_max=cold_end + slack, keys=keys)
+        self.stats["cold_scans"] += 1
+        self.stats["cold_events"] += lanes.count
+        self.stats["cold_columnar"] += 1
+        if lanes.count == 0:
+            return {}
+        aggs = reduce_columns(lanes.ts, lanes.key_codes, lanes.values,
+                              lanes.key_vocab, self.spec,
+                              interpret=self.interpret, with_min=True,
+                              route="query")
         hot_slots = {(k, row[0], row[1])
                      for k, rows in hot.items() for row in rows}
         out: Dict[str, List[SegmentRow]] = {}

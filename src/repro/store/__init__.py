@@ -17,6 +17,9 @@ only counted — a backend outage permanently dropped data.  Now:
                      are healthy (dedup-idempotent), events re-
                      aggregated through the Pallas batch path into the
                      live RuleEngine state              (replay.py)
+  Lanes              what a document's event is (time, key, value) and
+                     the column lanes every log's ``scan_lanes`` hands
+                     the batch path                     (lanes.py)
   StorePlane         the bundle AlertMixPipeline mounts when
                      ``PipelineConfig.store_dir`` is set  (this module)
   columnar/          the columnar store plane: binary column blocks for

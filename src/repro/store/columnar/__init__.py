@@ -10,15 +10,17 @@ retention, and tiered offload to an object store all ride ``tick``.
                          maintenance; ``scan_lanes()`` feeds the batch
                          kernel path with zero per-record Python
     Lanes                the column-array bundle scan_lanes returns
+                         (``repro.store.lanes``, shared with EventLog)
     LocalDirObjectStore  the reference offload backend
 """
-from .blocks import (Block, CorruptBlockError, default_key, encode_block,
-                     encode_file, iter_blocks)
-from .log import ColumnarEventLog, Lanes
+from ..lanes import Lanes
+from .blocks import (Block, CorruptBlockError, encode_block, encode_file,
+                     iter_blocks)
+from .log import ColumnarEventLog
 from .tiering import LocalDirObjectStore, ObjectStore, ObjectStoreError
 
 __all__ = [
     "Block", "ColumnarEventLog", "CorruptBlockError", "Lanes",
     "LocalDirObjectStore", "ObjectStore", "ObjectStoreError",
-    "default_key", "encode_block", "encode_file", "iter_blocks",
+    "encode_block", "encode_file", "iter_blocks",
 ]

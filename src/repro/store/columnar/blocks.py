@@ -32,9 +32,9 @@ gives the key lane's dictionary as bytes, with no string decoded.
 
 Partially-present columns carry a u8 presence mask.  Two reserved
 lanes are always written: ``_off`` (the record offsets — compaction
-makes them sparse) and ``_key`` (the pipeline's aggregation key,
-``doc.get("key", doc.get("channel", "all"))``, dict-encoded so scans
-get key codes without touching the documents).  Payload documents use
+makes them sparse) and ``_key`` (each document's aggregation key,
+``repro.store.lanes.doc_key``, dict-encoded so scans get key codes
+without touching the documents).  Payload documents use
 the ``{"id": ..., "doc": {...}}`` shape the store plane appends; doc
 fields become ``d:<field>`` columns and anything else falls into a
 ``_raw`` json column, so reconstruction is lossless.
@@ -49,6 +49,8 @@ import numpy as np
 
 from repro.obs.launches import record_columnar_block
 
+from ..lanes import decode_utf8, doc_key, is_number
+
 MAGIC = b"ACB2"
 LEGACY_MAGIC = b"ACB1"        # dictionaries as json in the header
 
@@ -57,11 +59,6 @@ Record = Tuple[int, object]          # (offset, payload)
 
 class CorruptBlockError(Exception):
     """A block failed its checksum or structural validation."""
-
-
-def default_key(doc: dict) -> str:
-    """The pipeline's aggregation key — mirrors AnalyticsStage."""
-    return str(doc.get("key", doc.get("channel", "all")))
 
 
 def _utf8(values: Sequence[str]) -> List[bytes]:
@@ -85,14 +82,6 @@ def _packed_bytes(buf, off: int, n: int) -> List[bytes]:
     return [blob[s:e] for s, e in zip([0] + ends[:-1], ends)]
 
 
-def decode_utf8(entries: List[bytes]) -> List[str]:
-    """utf-8 bytes back to strings, as ``_utf8`` wrote them."""
-    try:
-        return list(map(bytes.decode, entries))
-    except UnicodeDecodeError:          # a lone surrogate
-        return [b.decode("utf-8", "surrogatepass") for b in entries]
-
-
 def _classify(values: Sequence[object]) -> str:
     """Pick the narrowest lane that holds every present value."""
     kind = "i8"
@@ -114,7 +103,7 @@ def _classify(values: Sequence[object]) -> str:
 
 
 def encode_block(records: Sequence[Record], *,
-                 key_of: Callable[[dict], str] = default_key) -> bytes:
+                 key_of: Callable[[dict], str] = doc_key) -> bytes:
     """Encode one block of ``(offset, payload)`` records."""
     rows = len(records)
     if rows == 0:
@@ -217,9 +206,7 @@ def encode_block(records: Sequence[Record], *,
 
     # block stats: event-time + key range, for pruned scans
     ts_vals = [d["published_at"] for d in docs
-               if d is not None and isinstance(d.get("published_at"),
-                                               (int, float))
-               and not isinstance(d.get("published_at"), bool)]
+               if d is not None and is_number(d.get("published_at"))]
     real_keys = [k for k, d in zip(keys, docs) if d is not None]
     stats = {
         "min_ts": float(min(ts_vals)) if ts_vals else None,
@@ -317,8 +304,7 @@ class Block:
         if kind in ("f8", "i8"):
             out = np.asarray(vals, dtype=np.float64)
         else:
-            out = np.array([float(v) if isinstance(v, (int, float))
-                            and not isinstance(v, bool) else np.nan
+            out = np.array([float(v) if is_number(v) else np.nan
                             for v in vals], dtype=np.float64)
         if mask is not None:
             out = np.where(mask, out, np.nan)
@@ -333,8 +319,7 @@ class Block:
         if kind in ("f8", "i8"):
             out = np.asarray(vals, dtype=np.float64)
         else:
-            out = np.array([float(v) if isinstance(v, (int, float))
-                            and not isinstance(v, bool) else 1.0
+            out = np.array([float(v) if is_number(v) else 1.0
                             for v in vals], dtype=np.float64)
         if mask is not None:
             out = np.where(mask, out, 1.0)
@@ -443,7 +428,7 @@ def iter_blocks(data: bytes, *, want=None,
 
 
 def encode_file(records: Sequence[Record], *, block_rows: int,
-                key_of: Callable[[dict], str] = default_key) -> bytes:
+                key_of: Callable[[dict], str] = doc_key) -> bytes:
     """Encode records into a whole ``.colb`` file body."""
     out = bytearray()
     for i in range(0, len(records), block_rows):

@@ -30,16 +30,15 @@ import json
 import os
 import re
 import zlib
-from dataclasses import dataclass
-from itertools import count
 from typing import Iterator, List, Optional, Sequence, Set, Tuple
 
 import numpy as np
 
 from ..segment_log import (EventLog, Segment, CorruptSegmentError,
                            MANIFEST, _decode)
-from .blocks import (Block, CorruptBlockError, decode_utf8, default_key,
-                     encode_file, file_stats, iter_blocks)
+from ..lanes import Lanes, add_doc_rows, merge_lanes
+from .blocks import (Block, CorruptBlockError, encode_file, file_stats,
+                     iter_blocks)
 from .tiering import ObjectStore, ObjectStoreError
 
 _COLB_RE = re.compile(r"^seg-(\d{12})(?:\.g(\d+))?\.colb$")
@@ -48,48 +47,6 @@ _COLB_RE = re.compile(r"^seg-(\d{12})(?:\.g(\d+))?\.colb$")
 def _colb_name(first: int, gen: int = 0) -> str:
     return (f"seg-{first:012d}.colb" if gen == 0
             else f"seg-{first:012d}.g{gen}.colb")
-
-
-@dataclass
-class Lanes:
-    """Column arrays ready for the batch kernel path: one row per
-    event, already filtered/pruned — no per-record objects."""
-    ts: np.ndarray                      # float64 event times
-    key_codes: np.ndarray               # int64 codes into key_vocab
-    key_vocab: List[str]
-    values: np.ndarray                  # float64 value lane
-
-    @property
-    def count(self) -> int:
-        return int(self.ts.shape[0])
-
-
-def _empty_lanes() -> Lanes:
-    return Lanes(ts=np.empty(0), key_codes=np.empty(0, dtype=np.int64),
-                 key_vocab=[], values=np.empty(0))
-
-
-def _merge_lanes(entries: List[bytes], ts_parts: List[np.ndarray],
-                 entry_parts: List[np.ndarray],
-                 val_parts: List[np.ndarray]) -> Lanes:
-    """One vocabulary from the scanned key entries (utf-8 bytes), codes
-    in the order the keys were first seen, and the lanes concatenated.
-    One dict operation per entry: ``seen`` is the index of the entry's
-    first sighting, and those indices rise in the order the keys were
-    first seen, so ranking them gives the codes.  Only the distinct keys
-    are decoded to ``str``."""
-    if not ts_parts:
-        return _empty_lanes()
-    first: dict = {}
-    seen = np.fromiter(map(first.setdefault, entries, count()),
-                       dtype=np.int64, count=len(entries))
-    code_of = np.empty(len(entries), dtype=np.int64)
-    code_of[np.fromiter(first.values(), dtype=np.int64,
-                        count=len(first))] = np.arange(len(first))
-    return Lanes(ts=np.concatenate(ts_parts),
-                 key_codes=code_of[seen][np.concatenate(entry_parts)],
-                 key_vocab=decode_utf8(list(first)),
-                 values=np.concatenate(val_parts))
 
 
 class ColumnarEventLog(EventLog):
@@ -498,18 +455,17 @@ class ColumnarEventLog(EventLog):
                    ts_min: Optional[float] = None,
                    ts_max: Optional[float] = None,
                    keys: Optional[Sequence[str]] = None,
-                   include_tail: bool = True,
                    profiler=None) -> Lanes:
         """Gather ts/key/value lanes across the whole log: sealed
         columnar segments decode as numpy arrays (zero per-record
         Python); the JSON tail (and any legacy sealed JSONL) is
         materialized row by row — bounded by one segment's size.
 
-        Lane semantics match the pipeline's default extractors:
-        ``key = doc.get("key", doc.get("channel", "all"))``,
-        ``value = doc.get("value", 1.0)``, ``ts = doc["published_at"]``;
+        Documents are read by ``repro.store.lanes``' rule, as
+        ``EventLog.scan_lanes`` reads them: the blocks' lanes hold it
+        column by column, and the tail is read by the same row reader;
         rows without a numeric event time (and non-document payloads)
-        are dropped, exactly as the live path would reject them.
+        are dropped.
 
         The blocks' key dictionaries merge on their utf-8 bytes: codes
         follow each key's first sighting (blocks in log order, each
@@ -558,63 +514,33 @@ class ColumnarEventLog(EventLog):
                                           dtype=np.int64))
                 val_parts.append(bvals)
                 entries.extend(bentries)
-            if include_tail:
-                self._tail_lanes(from_offset, ts_min, ts_max, keyset,
-                                 entries, ts_parts, entry_parts, val_parts)
+            add_doc_rows(self._tail_records(from_offset), entries,
+                         ts_parts, entry_parts, val_parts, ts_min=ts_min,
+                         ts_max=ts_max, keyset=keyset)
         with stage("decode.keys"):
-            lanes = _merge_lanes(entries, ts_parts, entry_parts, val_parts)
+            lanes = merge_lanes(entries, ts_parts, entry_parts, val_parts)
             # the parts hold views of the segments' bytes and one object
             # per entry: free them inside the stage that used them last
             del entries, ts_parts, entry_parts, val_parts
         return lanes
 
-    def _tail_lanes(self, from_offset: int, ts_min: Optional[float],
-                    ts_max: Optional[float], keyset: Optional[Set[str]],
-                    entries: List[bytes], ts_parts: List[np.ndarray],
-                    entry_parts: List[np.ndarray],
-                    val_parts: List[np.ndarray]) -> None:
-        """``scan_lanes``' rows of the JSON tail and of any legacy sealed
-        JSONL, appended to its parts; each row's key is one more entry."""
+    def _tail_records(self, from_offset: int
+                      ) -> Iterator[Tuple[int, object]]:
+        """The records at or past ``from_offset`` of the JSON tail and
+        of any legacy sealed JSONL: what ``scan_lanes`` reads row by
+        row."""
         with self._lock:
             sealed = list(self._sealed)
             active = self._active_name
             if self._fh is not None:
                 self._fh.flush()
-        tail_rows: List[Tuple[float, int, float]] = []
         names = [s.name for s in sealed
                  if s.last >= from_offset and not s.name.endswith(".colb")]
         if active is not None:
             names.append(active)
         for name in names:
             recs, _ = self._scan_file(name)
-            for off, payload in recs:
-                if off < from_offset:
-                    continue
-                if not (isinstance(payload, dict)
-                        and isinstance(payload.get("doc"), dict)):
-                    continue
-                doc = payload["doc"]
-                ts = doc.get("published_at")
-                if isinstance(ts, bool) or not isinstance(ts, (int, float)):
-                    continue
-                ts = float(ts)
-                if ts_min is not None and ts < ts_min:
-                    continue
-                if ts_max is not None and ts >= ts_max:
-                    continue
-                key = default_key(doc)
-                if keyset is not None and key not in keyset:
-                    continue
-                v = doc.get("value", 1.0)
-                v = float(v) if isinstance(v, (int, float)) \
-                    and not isinstance(v, bool) else 1.0
-                tail_rows.append((ts, len(entries), v))
-                entries.append(key.encode("utf-8", "surrogatepass"))
-        if tail_rows:
-            arr = np.array(tail_rows, dtype=np.float64)
-            ts_parts.append(arr[:, 0])
-            entry_parts.append(arr[:, 1].astype(np.int64))
-            val_parts.append(arr[:, 2])
+            yield from (r for r in recs if r[0] >= from_offset)
 
     # ---- keyed compaction (keep-last-per-doc-id) ----------------------------
     def _compact_plan(self) -> Optional[dict]:

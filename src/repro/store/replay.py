@@ -14,10 +14,13 @@ Two backlog families, two drain routes:
       (terminal emitted-counter delta), so a mid-replay outage never
       poisons the dedup window.
 
-  late_event / raw log ranges   event payloads are packed through the
-      hardware-speed batch path — ``alerts.batch.pack_events`` ->
-      the Pallas ``window_reduce`` kernel -> ``WindowAggregate``s — and
-      evaluated by the SAME RuleEngine instance the live
+  late_event / raw log ranges   events reach the hardware-speed batch
+      path as column lanes — a log range through the log's own
+      ``scan_lanes`` (documents read by ``repro.store.lanes``' rule,
+      whichever log it is), journaled late events coded into the same
+      ``Lanes`` — then ``alerts.batch.reduce_columns`` (pack_columns ->
+      the Pallas ``window_reduce`` kernel -> ``WindowAggregate``s), and
+      are evaluated by the SAME RuleEngine instance the live
       ``WindowOperator`` feeds, so replayed windows flow into the same
       rule state/history and the same AlertSink subscribers (parity
       with the live path is test-enforced).
@@ -31,10 +34,11 @@ from __future__ import annotations
 import contextlib
 import json
 import threading
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import List, Optional, Sequence, Tuple
 
 from repro.core.dedup import DedupWindow, content_hash
 from repro.obs import StageProfiler
+from repro.store.lanes import Lanes
 
 Event = Tuple[str, float, float]          # (key, event_time, value)
 
@@ -43,25 +47,19 @@ class ReplayEngine:
     """Drains journal/log backlogs; see module docstring.
 
     ``analytics`` is the live ``repro.alerts.AnalyticsStage`` — its
-    WindowSpec, key/time/value extractors, and RuleEngine are reused so
-    batch-replayed aggregates land in the same state the live operator
-    feeds.  ``journal`` is a ``DeadLetterJournal``; ``log`` the document
-    ``EventLog`` (payloads ``{"id": ..., "doc": {...}}``).
+    WindowSpec and RuleEngine are reused so batch-replayed aggregates
+    land in the same state the live operator feeds.  ``journal`` is a
+    ``DeadLetterJournal``; ``log`` the document log, an ``EventLog`` or
+    a ``ColumnarEventLog`` (payloads ``{"id": ..., "doc": {...}}``),
+    whose ``scan_lanes`` says what each document's event is
+    (``repro.store.lanes``), not the stage's extractors.
     """
 
     def __init__(self, *, journal=None, log=None, analytics=None,
-                 dedup_window: int = 1 << 16, interpret=None,
-                 columnar_lanes: Optional[bool] = None):
+                 dedup_window: int = 1 << 16, interpret=None):
         self.journal = journal
         self.log = log
         self.analytics = analytics
-        # columnar fast path: when the log is a ColumnarEventLog,
-        # ``replay_log`` reads column lanes instead of per-record
-        # payloads.  Lane semantics equal the pipeline's DEFAULT
-        # key/time/value extractors — pass ``columnar_lanes=False`` if
-        # this engine's AnalyticsStage uses custom extractors.  None
-        # (the default) auto-enables iff the log grows ``scan_lanes``.
-        self.columnar_lanes = columnar_lanes
         self.dedup = DedupWindow(dedup_window)
         self.interpret = interpret
         self._lock = threading.Lock()
@@ -171,26 +169,44 @@ class ReplayEngine:
     def replay_events(self, events: Sequence[Event], *,
                       watermark: Optional[float] = None,
                       route: str = "replay") -> tuple:
-        """Run raw events through pack_events -> window_reduce -> the
-        live RuleEngine.  Returns (aggregates, fired alerts).  Session
-        specs are cut into the sessions these events alone form.
-        ``route`` names the caller for the kernel's launch counters."""
+        """Run raw (key, event_time, value) events through the batch
+        path into the live RuleEngine: their keys are coded into
+        ``Lanes``, which go the way ``replay_columns``' do.  Returns
+        (aggregates, fired alerts).  Session specs are cut into the
+        sessions these events alone form.  ``route`` names the caller
+        for the kernel's launch counters."""
+        from repro.alerts.batch import code_events
+
+        ts, codes, values, vocab = code_events(list(events))
+        return self._replay("replay.events",
+                            Lanes(ts=ts, key_codes=codes, key_vocab=vocab,
+                                  values=values), watermark, route)
+
+    def replay_columns(self, lanes: Lanes, *,
+                       watermark: Optional[float] = None) -> tuple:
+        """Run column lanes (a log's ``scan_lanes`` output) through
+        pack_columns -> window_reduce -> the live RuleEngine, for every
+        window kind (session specs take the session layout)."""
+        return self._replay("replay.columns", lanes, watermark, "replay")
+
+    def _replay(self, span: str, lanes: Lanes, watermark: Optional[float],
+                route: str) -> tuple:
         if self.analytics is None:
             raise RuntimeError("no AnalyticsStage attached")
-        from repro.alerts.batch import reduce_events
+        from repro.alerts.batch import reduce_columns
 
         spec = self.analytics.operator.spec
-        events = list(events)
         ctx = (contextlib.nullcontext() if self.tracer is None
-               else self.tracer.span("replay.events",
-                                     attrs={"events": len(events)}))
+               else self.tracer.span(span, attrs={"events": lanes.count}))
         with ctx:
             # with_min: replayed windows reach the query plane's hot store
             # and the rules as whole aggregates; a window made only of
             # replayed events would otherwise carry min=+inf
-            aggs = reduce_events(events, spec, interpret=self.interpret,
-                                 profiler=self.profiler, with_min=True,
-                                 route=route)
+            aggs = reduce_columns(lanes.ts, lanes.key_codes, lanes.values,
+                                  lanes.key_vocab, spec,
+                                  interpret=self.interpret,
+                                  profiler=self.profiler, with_min=True,
+                                  route=route)
             wm = watermark if watermark is not None \
                 else self.analytics.operator.watermark
             for a in aggs:
@@ -205,82 +221,28 @@ class ReplayEngine:
                 if export is not None:
                     export(aggs, wm)
         with self._lock:
-            self.stats["events_replayed"] += len(events)
-            self.stats["aggregates"] += len(aggs)
-            self.stats["alerts"] += len(fired)
-        return aggs, fired
-
-    def replay_columns(self, lanes, *,
-                       watermark: Optional[float] = None) -> tuple:
-        """Run column lanes (``ColumnarEventLog.scan_lanes`` output)
-        through pack_columns -> window_reduce -> the live RuleEngine —
-        the zero-per-record-Python twin of ``replay_events``, for every
-        window kind (session specs take the session layout)."""
-        if self.analytics is None:
-            raise RuntimeError("no AnalyticsStage attached")
-        from repro.alerts.batch import reduce_columns
-
-        spec = self.analytics.operator.spec
-        ctx = (contextlib.nullcontext() if self.tracer is None
-               else self.tracer.span("replay.columns",
-                                     attrs={"events": lanes.count}))
-        with ctx:
-            aggs = reduce_columns(lanes.ts, lanes.key_codes, lanes.values,
-                                  lanes.key_vocab, spec,
-                                  interpret=self.interpret,
-                                  profiler=self.profiler, with_min=True,
-                                  route="replay")
-            wm = watermark if watermark is not None \
-                else self.analytics.operator.watermark
-            for a in aggs:
-                a.closed_at_watermark = wm
-            with self.profiler.stage("state_merge"):
-                fired = self.analytics.engine.process(aggs)
-                export = getattr(self.analytics, "export_closed", None)
-                if export is not None:
-                    export(aggs, wm)
-        with self._lock:
             self.stats["events_replayed"] += lanes.count
             self.stats["aggregates"] += len(aggs)
             self.stats["alerts"] += len(fired)
         return aggs, fired
 
     def replay_log(self, from_offset: int = 0, *,
-                   watermark: Optional[float] = None,
-                   columnar: Optional[bool] = None) -> dict:
+                   watermark: Optional[float] = None) -> dict:
         """Replay a document-log range through the batch path (the
         backfill read of the unified log: same records the live path
-        consumed, re-aggregated at kernel speed).  On a columnar log
-        the scan itself is columnar — sealed segments decode straight
-        into numpy lanes, no per-record Python (``columnar`` overrides
-        the engine-level ``columnar_lanes`` gate)."""
+        consumed, re-aggregated at kernel speed).  The log hands its
+        documents out as lanes (``scan_lanes``, timed as the ``decode``
+        stage; a columnar log's sealed segments decode straight into
+        numpy lanes, no per-record Python), then ``replay_columns``."""
         if self.log is None:
             raise RuntimeError("no EventLog attached")
-        use = columnar if columnar is not None else (
-            self.columnar_lanes if self.columnar_lanes is not None
-            else hasattr(self.log, "scan_lanes"))
-        if use and hasattr(self.log, "scan_lanes"):
-            with self.profiler.stage("decode"):   # columnar block scan
-                lanes = self.log.scan_lanes(from_offset,
-                                            profiler=self.profiler)
-            last = self.log.next_offset - 1
-            aggs, fired = self.replay_columns(lanes, watermark=watermark)
-            return {"events": lanes.count, "aggregates": len(aggs),
-                    "alerts": len(fired), "last_offset": last,
-                    "columnar": True}
-        stage = self.analytics
-        events: List[Event] = []
-        last = from_offset - 1
-        with self.profiler.stage("decode"):     # disk scan + extraction
-            for off, payload in self.log.scan(from_offset):
-                doc = payload["doc"]
-                events.append((stage.key_fn(doc), stage.time_fn(doc),
-                               stage.value_fn(doc)))
-                last = off
-        aggs, fired = self.replay_events(events, watermark=watermark)
-        return {"events": len(events), "aggregates": len(aggs),
+        with self.profiler.stage("decode"):
+            lanes = self.log.scan_lanes(from_offset, profiler=self.profiler)
+        last = self.log.next_offset - 1
+        aggs, fired = self.replay_columns(lanes, watermark=watermark)
+        return {"events": lanes.count, "aggregates": len(aggs),
                 "alerts": len(fired), "last_offset": last,
-                "columnar": False}
+                "columnar": True}
 
     def replay_late_events(self, *, watermark: Optional[float] = None,
                            max_records: Optional[int] = None) -> dict:

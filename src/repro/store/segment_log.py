@@ -39,6 +39,8 @@ import zlib
 from dataclasses import dataclass, field
 from typing import Iterator, List, Optional, Sequence, Tuple
 
+from repro.store.lanes import Lanes, add_doc_rows, merge_lanes
+
 MANIFEST = "manifest.json"
 _SEG_FMT = "seg-{:012d}.jsonl"
 
@@ -106,6 +108,7 @@ class EventLog:
 
       append(batch)       -> (first_offset, last_offset) of the batch
       scan(from_offset)   -> iterator of (offset, payload)
+      scan_lanes(...)     -> the documents' event lanes (``Lanes``)
       truncate(upto)      -> drop sealed segments entirely below ``upto``
       close()/reopen      -> crash-tolerant (torn tails skipped)
 
@@ -334,6 +337,22 @@ class EventLog:
             for off, payload in recs:
                 if off >= from_offset:
                     yield off, payload
+
+    def scan_lanes(self, from_offset: int = 0, *,
+                   ts_min: Optional[float] = None,
+                   ts_max: Optional[float] = None,
+                   keys: Optional[Sequence[str]] = None,
+                   profiler=None) -> Lanes:
+        """The documents at or past ``from_offset`` as event lanes, read
+        row by row by ``repro.store.lanes``' rule: rows with an event
+        time in ``[ts_min, ts_max)`` and, when ``keys`` is given, a key
+        among them.  Key codes follow each key's first sighting.
+        ``profiler`` is taken so both logs share one call; a row log
+        times no sub-stages."""
+        parts: Tuple[List, List, List, List] = ([], [], [], [])
+        add_doc_rows(self.scan(from_offset), *parts, ts_min=ts_min,
+                     ts_max=ts_max, keyset=None if keys is None else set(keys))
+        return merge_lanes(*parts)
 
     def truncate(self, upto: int) -> int:
         """Release sealed segments whose LAST offset is below ``upto``;

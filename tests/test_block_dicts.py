@@ -17,7 +17,8 @@ from repro.obs import columnar_blocks_decoded
 from repro.store.columnar import ColumnarEventLog, encode_block, iter_blocks
 from repro.store.columnar.blocks import (LEGACY_MAGIC, MAGIC,
                                          CorruptBlockError, Record,
-                                         _classify, default_key)
+                                         _classify)
+from repro.store.lanes import doc_key
 from repro.store.segment_log import CorruptSegmentError
 
 HOSTILE = ["", "\0", "a\0b", "ab\0", "\0\0", "текст", "🦉 ключ", "k\ud800",
@@ -28,7 +29,7 @@ HOSTILE = ["", "\0", "a\0b", "ab\0", "\0\0", "текст", "🦉 ключ", "k\u
 # ---- into the payload: each dictionary is a json list in the header
 
 def legacy_encode_block(records: Sequence[Record], *,
-                        key_of: Callable[[dict], str] = default_key
+                        key_of: Callable[[dict], str] = doc_key
                         ) -> bytes:
     rows = len(records)
     offs = np.array([o for o, _ in records], dtype="<i8")
@@ -186,7 +187,7 @@ def legacy_scan_lanes(log, from_offset=0, *, ts_min=None, ts_max=None,
         if (ts_min is not None and ts < ts_min) or \
                 (ts_max is not None and ts >= ts_max):
             continue
-        key = default_key(doc)
+        key = doc_key(doc)
         if keyset is not None and key not in keyset:
             continue
         v = doc.get("value", 1.0)
@@ -373,7 +374,7 @@ def test_a_legacy_or_mixed_log_scans_like_its_records(tmp_path, every):
                                               []) + _records(25, start=400))]
     ts, codes, vocab, values = _lanes(log.scan_lanes())
     # the record scan, folded as the pipeline's extractors read it
-    rows = [(p["doc"]["published_at"], default_key(p["doc"]),
+    rows = [(p["doc"]["published_at"], doc_key(p["doc"]),
              p["doc"]["value"] if not isinstance(p["doc"]["value"], bool)
              else 1.0)
             for _, p in log.scan()

@@ -53,7 +53,7 @@ def test_pipeline_routes_match_their_references(tmp_path):
 
 
 def test_direct_and_sliding_batches_match_the_reference():
-    from repro.store.columnar.log import Lanes
+    from repro.store.columnar import Lanes
 
     rng = np.random.default_rng(1)
     n = 400

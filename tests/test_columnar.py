@@ -332,7 +332,7 @@ def test_pipeline_columnar_replay_and_maintenance(tmp_path):
         compact_interval_s=900.0, offload_dir=str(tmp_path / "objects"),
         offload_keep_local=1, analytics=True), seed=0)
     p.run_for(3600, dt=5.0)
-    res = p.store.replay.replay_log(0, columnar=True)
+    res = p.store.replay.replay_log(0)
     assert res["columnar"] is True
     assert res["events"] == p.store_stats()["appended_records"]
     st = p.store_stats()["columnar"]

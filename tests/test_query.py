@@ -268,11 +268,16 @@ def test_hot_query_matches_reference():
 # (acceptance criterion (c): result parity vs pure-Python reference)
 # ---------------------------------------------------------------------------
 
-def test_cold_range_query_parity_with_reference():
+@pytest.mark.parametrize("store_columnar", [False, True],
+                         ids=["json", "columnar"])
+def test_cold_range_query_parity_with_reference(store_columnar):
+    """Either store answers a cold range by the same lanes route, with
+    the answer of the pure-Python reference over its log."""
     import tempfile
     pipe = AlertMixPipeline(
         PipelineConfig(num_sources=200, analytics=True, query=True,
                        store_dir=tempfile.mkdtemp(), window_size_s=60.0,
+                       store_columnar=store_columnar,
                        query_max_windows_per_key=5), seed=0)
     try:
         pipe.run_for(2400.0)
@@ -282,6 +287,7 @@ def test_cold_range_query_parity_with_reference():
         # the full range spans evicted + retained windows
         assert res.source == "mixed"
         assert pipe.query.status()["cold_scans"] == 1
+        assert pipe.query.status()["cold_columnar"] == 1
         got = {(p["start"], p["end"]): p["count"] for p in res.points}
         assert got == _reference_counts(pipe, "news", 0.0, 2400.0)
         # a purely-cold range too
@@ -317,7 +323,6 @@ def test_cold_range_query_parity_on_columnar_store():
         pipe.run_for(2400.0)
         st = pipe.query.status()
         assert st["evicted_windows"] > 0 and st["floor"] > 0.0
-        assert pipe.query.engine.columnar_lanes is True
         res = pipe.query.query(
             AggQuery(channel="news", start=0.0, end=2400.0))
         assert res.source == "mixed"

@@ -154,16 +154,33 @@ def test_case_shapes():
     assert len(bridged) == 3 and bridged[("b", 100.0)][1] == 1
 
 
-def test_pack_events_equals_pack_columns():
+@pytest.mark.parametrize("spec", [
+    WindowSpec(kind="tumbling", size_s=60.0),
+    WindowSpec(kind="sliding", size_s=60.0, slide_s=20.0),
+    SPEC], ids=["tumbling", "sliding", "session"])
+def test_pack_events_equals_pack_columns(spec):
+    """``pack_events`` lays triples out as ``pack_columns`` lays out their
+    keys coded in sorted order, for every window kind; each membership
+    lands in a slot that holds its event (``WindowSpec.assign``'s slots,
+    one membership per slot and event, for the fixed kinds)."""
     keys, ts, vals = CASES["many_keys"]
     vocab, codes = np.unique(np.asarray(keys, object), return_inverse=True)
-    packed, seg_ids, slots = pack_columns(ts, codes, vals, SPEC)
+    packed, seg_ids, slots = pack_columns(ts, codes, vals, spec)
     e_packed, e_seg_ids, e_slots = pack_events(list(zip(keys, ts, vals)),
-                                               SPEC)
+                                               spec)
     assert packed.tobytes() == e_packed.tobytes()
     assert seg_ids.tobytes() == e_seg_ids.tobytes()
     assert e_slots == [(vocab[c], s, e) for c, s, e in slots]
     assert all(type(k) is str for k, _, _ in e_slots)
+    assert e_slots == sorted(e_slots, key=lambda s: (s[1], s[0]))
+    if spec.kind == "session":
+        return
+    want = sorted((k, s, e, float(np.float32(v)))
+                  for k, t, v in zip(keys, ts, vals)
+                  for s, e in spec.assign(float(t)))
+    got = sorted(e_slots[i] + (v,) for i, v in zip(e_seg_ids.tolist(),
+                                                   e_packed.tolist()))
+    assert got == want
 
 
 def test_session_layout_order_and_counters():

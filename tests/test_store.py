@@ -553,7 +553,7 @@ def test_replayed_windows_carry_their_min(route):
     """A window made only of replayed events reaches the query plane's
     hot store and the rules as a whole aggregate: its min is the events'
     min, not the +inf of an empty accumulator."""
-    from repro.store.columnar.log import Lanes
+    from repro.store.columnar import Lanes
 
     stage = AnalyticsStage(WindowSpec(size_s=60.0), [])
     eng = ReplayEngine(analytics=stage, interpret=True)
