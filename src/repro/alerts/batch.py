@@ -18,13 +18,15 @@ aggregates (tested), so rules don't care which path fed them.
 """
 from __future__ import annotations
 
-from typing import List, Sequence, Tuple
+from itertools import repeat
+from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
 
 from repro.alerts.windows import (SESSION, TUMBLING, WindowAggregate,
                                   WindowSpec)
-from repro.obs.launches import record_pack_sessions, record_pack_slot_index
+from repro.obs.launches import (record_pack_sessions, record_pack_slot_index,
+                                record_unpack_order)
 
 Event = Tuple[str, float, float]          # (key, event_time, value)
 Slot = Tuple[str, float, float]           # (key, window_start, window_end)
@@ -34,6 +36,10 @@ Slot = Tuple[str, float, float]           # (key, window_start, window_end)
 # the memberships' keys) when the range is at most this many times the
 # memberships; past it, sorting the keys costs less memory and time
 _DENSE_KEYS_PER_MEMBERSHIP = 4
+
+# a slot of pack_columns' list, read into one record array
+_SLOT = np.dtype([("code", np.int64), ("start", np.float64),
+                  ("end", np.float64)])
 
 
 def code_events(events: Sequence[Event]):
@@ -207,17 +213,22 @@ def _pack_sessions(ts, key_codes, values, gap_s: float, stage):
 def reduce_columns(ts: np.ndarray, key_codes: np.ndarray,
                    values: np.ndarray, key_vocab: Sequence[str],
                    spec: WindowSpec, *, interpret=None, profiler=None,
-                   with_min: bool = False,
-                   route: str = "direct") -> List[WindowAggregate]:
-    """One kernel launch -> WindowAggregates for every touched slot:
-    pack_columns -> window_reduce -> unpack.  Per-record Python appears
-    only in the final per-SLOT unpack (S slots, not N events); a slot's
-    key is ``key_vocab[code]``.
+                   with_min: bool = False, route: str = "direct",
+                   closed_at: float = 0.0) -> List[WindowAggregate]:
+    """One kernel launch -> WindowAggregates for every touched slot, in
+    (window end, key) order: pack_columns -> window_reduce -> unpack.
+    Per-record Python appears only in the unpack's one construction per
+    SLOT (S slots, not N events); a slot's key is ``key_vocab[code]``,
+    and the vocabulary holds distinct keys.
 
     ``profiler`` (a ``repro.obs.StageProfiler``) itemizes the chain into
     pack_events / kernel / unpack stages, the kernel stage into
-    ``kernel.dispatch`` / ``kernel.wait`` / ``kernel.fetch`` per launch.
-    ``route`` names the caller for the kernel's launch counters.
+    ``kernel.dispatch`` / ``kernel.wait`` / ``kernel.fetch`` per launch,
+    the unpack into ``unpack.order`` (``_record_order``) and
+    ``unpack.records`` (the lanes gathered in that order, their columns
+    converted once with ``.tolist()``, one positional construction a
+    record).  ``route`` names the caller for the kernel's launch
+    counters; ``closed_at`` is every record's ``closed_at_watermark``.
 
     ``with_min=True`` adds a second launch over the negated values —
     ``min(v) = -max(-v)`` — so per-slot minima come out of the same
@@ -241,18 +252,49 @@ def reduce_columns(ts: np.ndarray, key_codes: np.ndarray,
                                  interpret=interpret, route=route,
                                  negate=True)[:, 3]
     with stage("unpack"):
-        out: List[WindowAggregate] = []
-        for sid, (code, start, end) in enumerate(slots):
-            cnt, sm, sq, mx = lanes[sid]
-            agg = WindowAggregate(
-                key=key_vocab[code], window_start=start, window_end=end,
-                count=int(round(cnt)), sum=float(sm), sumsq=float(sq),
-                max=float(mx))
-            if mins is not None:
-                agg.min = float(mins[sid])
-            out.append(agg)
-        out.sort(key=lambda a: (a.window_end, a.key))
+        with stage("unpack.order"):
+            order = _record_order(slots, key_vocab)
+        with stage("unpack.records"):
+            if order is not None:
+                slots = [slots[i] for i in order.tolist()]
+                lanes = lanes[order]
+                if mins is not None:
+                    mins = mins[order]
+            # np.rint rounds half to even, as round() does: the counts
+            # int(round(c)) gives for every float32 count
+            counts = np.rint(lanes[:, 0]).astype(np.int64).tolist()
+            sums, sumsqs, maxs = lanes[:, 1:].astype(np.float64).T.tolist()
+            mins = (repeat(float("inf")) if mins is None
+                    else mins.astype(np.float64).tolist())
+            out = [WindowAggregate(key_vocab[code], start, end, n, sm, sq,
+                                   mx, 0.0, closed_at, mn)
+                   for (code, start, end), n, sm, sq, mx, mn
+                   in zip(slots, counts, sums, sumsqs, maxs, mins)]
     return out
+
+
+def _record_order(slots: Sequence[Tuple[int, float, float]],
+                  key_vocab: Sequence[str]) -> Optional[np.ndarray]:
+    """The permutation that takes ``pack_columns``' slots, in (start,
+    code) order, to the records' (window end, key) order, or None where
+    the slot order already is it.
+
+    Codes are not in key order (``scan_lanes`` codes keys as first
+    seen), so the vocabulary is ranked by ``str`` order; ``np.lexsort``
+    over (end, rank) is stable, as a sort on (end, key) is, and no two
+    slots share an end and a key.  Each call counts its outcome in
+    ``repro.obs.unpack_order()``: ``slot`` (the identity, as fixed
+    windows over a sorted vocabulary give) or ``sorted``."""
+    cols = np.fromiter(slots, _SLOT, count=len(slots))
+    k = len(key_vocab)
+    rank = np.empty(k, np.int64)
+    rank[sorted(range(k), key=key_vocab.__getitem__)] = np.arange(k)
+    order = np.lexsort((rank[cols["code"]], cols["end"]))
+    if (order[1:] > order[:-1]).all():
+        record_unpack_order("slot")
+        return None
+    record_unpack_order("sorted")
+    return order
 
 
 def reduce_events(events: Sequence[Event], spec: WindowSpec, *,

@@ -69,7 +69,7 @@ from repro.core.sources import NOT_MODIFIED, SourceSimulator
 from repro.delivery import BatchingSink, FanOutSink, RetryingSink, as_sink
 from repro.obs import (LatencySink, Observability, TracingSink,
                        columnar_blocks_decoded, kernel_launches,
-                       pack_sessions, pack_slot_index)
+                       pack_sessions, pack_slot_index, unpack_order)
 
 # repro.ingest imports repro.core.registry (which runs this package's
 # __init__) — import it lazily to keep `import repro.ingest` first legal
@@ -1228,6 +1228,11 @@ class AlertMixPipeline:
             c("pack_slot_index_total",
               "column packs per slot-index path (dense table, sort "
               "or session layout)").sync(n, path=path)
+        for order, n in unpack_order().items():
+            c("unpack_order_total",
+              "unpacks of the kernel's lanes by record order (the "
+              "pack's slot order, or sorted by window end and key)").sync(
+                n, order=order)
         for cut, n in pack_sessions().items():
             c("pack_sessions_total",
               "sessions laid out by session packs, by what opened "

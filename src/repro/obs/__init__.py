@@ -19,9 +19,10 @@ closes the loop the paper leaves to AWS:
   kernel_launches  device launches per kernel and route (replay,
                    drain, query), with the new shapes each compiled;
                    beside it pack_slot_index, column packs per
-                   slot-index path (dense, sort, session), and
-                   pack_sessions, packed sessions per cut (key,
-                   gap), and columnar_blocks_decoded, store blocks
+                   slot-index path (dense, sort, session),
+                   unpack_order, unpacks per record order (slot,
+                   sorted), pack_sessions, packed sessions per cut
+                   (key, gap), and columnar_blocks_decoded, store blocks
                    read per dictionary layout (payload,
                    header)                        (launches.py)
   MetricsConnector self-monitoring: registry snapshots re-enter the
@@ -51,7 +52,7 @@ from repro.obs.metrics import (
 )
 from repro.obs.latency import LatencySink, LatencyTracker
 from repro.obs.launches import (columnar_blocks_decoded, kernel_launches,
-                                pack_sessions, pack_slot_index)
+                                pack_sessions, pack_slot_index, unpack_order)
 from repro.obs.profiler import StageProfiler, recent_passes
 from repro.obs.slo import SLOEngine, SLOSpec
 from repro.obs.trace import Span, TraceExporter, Tracer, TracingSink
@@ -82,5 +83,5 @@ __all__ = [
     "MetricsRegistry", "Observability", "SLOEngine", "SLOSpec",
     "Span", "StageProfiler", "TraceExporter", "Tracer", "TracingSink",
     "columnar_blocks_decoded", "kernel_launches", "pack_sessions",
-    "pack_slot_index", "recent_passes",
+    "pack_slot_index", "recent_passes", "unpack_order",
 ]

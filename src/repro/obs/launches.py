@@ -21,6 +21,10 @@ key's range), ``sort`` (a sort of the keys) or ``session`` (the session
 layout: a sort by key and event time, cut at idle gaps), and
 ``pack_sessions()`` counts the sessions those session packs laid out by
 what opened each: a new ``key`` or a ``gap`` longer than the spec's.
+``unpack_order()`` counts the unpacks of the kernel's lanes
+(``repro.alerts.batch.reduce_columns``) by whether the pack's ``slot``
+order already was the records' (window end, key) order, or the slots
+were ``sorted`` into it.
 ``columnar_blocks_decoded()`` counts the columnar store's blocks read
 and checksummed (``repro.store.columnar.blocks.iter_blocks``) by where
 each keeps its string dictionaries: in the checksummed ``payload``, or
@@ -97,6 +101,23 @@ def pack_slot_index() -> Dict[str, int]:
     """Snapshot of the process's column packs per slot-index path."""
     with _pack_lock:
         return dict(_pack_paths)
+
+
+UNPACK_ORDERS = ("slot", "sorted")
+_unpack_orders: Dict[str, int] = dict.fromkeys(UNPACK_ORDERS, 0)
+
+
+def record_unpack_order(order: str) -> None:
+    """Count one unpack whose records took ``order``, one of
+    ``UNPACK_ORDERS``."""
+    with _pack_lock:
+        _unpack_orders[order] += 1
+
+
+def unpack_order() -> Dict[str, int]:
+    """Snapshot of the process's unpacks per record order."""
+    with _pack_lock:
+        return dict(_unpack_orders)
 
 
 SESSION_CUTS = ("key", "gap")

@@ -198,6 +198,8 @@ class ReplayEngine:
         spec = self.analytics.operator.spec
         ctx = (contextlib.nullcontext() if self.tracer is None
                else self.tracer.span(span, attrs={"events": lanes.count}))
+        wm = watermark if watermark is not None \
+            else self.analytics.operator.watermark
         with ctx:
             # with_min: replayed windows reach the query plane's hot store
             # and the rules as whole aggregates; a window made only of
@@ -206,11 +208,7 @@ class ReplayEngine:
                                   lanes.key_vocab, spec,
                                   interpret=self.interpret,
                                   profiler=self.profiler, with_min=True,
-                                  route=route)
-            wm = watermark if watermark is not None \
-                else self.analytics.operator.watermark
-            for a in aggs:
-                a.closed_at_watermark = wm
+                                  route=route, closed_at=wm)
             with self.profiler.stage("state_merge"):
                 fired = self.analytics.engine.process(aggs)
                 # replayed windows bypass AnalyticsStage.advance, so feed

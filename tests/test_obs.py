@@ -690,6 +690,37 @@ def test_pack_slot_index_paths_exported_after_columnar_replay(tmp_path):
         p.close()
 
 
+def test_unpack_order_exported_after_columnar_replay(tmp_path):
+    """A columnar log replay unpacks the kernel's lanes once: its keys,
+    coded as first seen (``sports`` before ``news``), are not in key
+    order, so the slots are counted ``sorted``; every scrape exports
+    ``unpack_order_total{order}`` beside ``pack_slot_index_total``."""
+    from repro.obs import unpack_order
+
+    p = AlertMixPipeline(
+        PipelineConfig(num_sources=0, analytics=True, store_columnar=True,
+                       store_dir=str(tmp_path / "s")), seed=0)
+    try:
+        p.store.append_documents(
+            [(f"d{i}", {"title": "t", "published_at": float(i % 600),
+                        "channel": "news" if i % 3 else "sports"})
+             for i in range(120)])
+        p.store.log.roll()
+        before = unpack_order()
+        p.store.replay.replay_log(0, watermark=1e9)
+        after = unpack_order()
+        assert after["sorted"] - before["sorted"] == 1
+        assert after["slot"] == before["slot"]
+        text = p.metrics_text()
+        for order in ("slot", "sorted"):
+            assert f'unpack_order_total{{order="{order}"}}' in text
+        assert 'pack_slot_index_total{path="dense"}' in text
+        assert p.obs.metrics.counter("unpack_order_total").value(
+            order="sorted") == after["sorted"]
+    finally:
+        p.close()
+
+
 def test_rule_engine_add_rule_rejects_duplicates():
     from repro.alerts import RuleEngine, ThresholdRule
 

@@ -799,7 +799,8 @@ def test_replay_log_stages_are_host_events_on_the_profiler_trace(tmp_path):
     for stage in ("decode", "pack_events", "pack_events.assign",
                   "pack_events.unique", "pack_events.slots", "kernel",
                   "kernel.dispatch", "kernel.wait", "kernel.fetch",
-                  "unpack", "state_merge"):
+                  "unpack", "unpack.order", "unpack.records",
+                  "state_merge"):
         assert f"replay.{stage}" in names, stage
     assert "window_reduce.replay" in names
 
@@ -827,6 +828,28 @@ def test_replay_sub_stages_nest_inside_their_stages(tmp_path):
     assert [st for st, _ in kernel] == [
         "kernel.dispatch", "kernel.wait", "kernel.fetch"] * 2
     assert [x for _, x in kernel] == sorted(x for _, x in kernel)
+
+
+def test_unpack_sub_stages_nest_inside_unpack_once_a_call(tmp_path):
+    """The unpack is timed in two sub-stages, ``unpack.order`` then
+    ``unpack.records``, each once per ``reduce_columns`` call and inside
+    its ``unpack`` pass; the replay's records carry its watermark."""
+    from repro.obs.profiler import recent_passes
+
+    eng = _columnar_replay_engine(tmp_path)
+    eng.replay_log(0, watermark=1e9)
+    for _ in range(2):
+        n = len(recent_passes())
+        aggs, _ = eng.replay_columns(eng.log.scan_lanes(0), watermark=77.0)
+        passes = [(st, t0, t0 + dt) for prof, st, t0, dt
+                  in recent_passes()[n:] if prof == "replay"
+                  and st.startswith("unpack")]
+        assert [st for st, _, _ in passes] == [
+            "unpack.order", "unpack.records", "unpack"]
+        (_, a, b), = [p for p in passes if p[0] == "unpack"]
+        assert all(a <= x <= y <= b for _, x, y in passes)
+        assert passes[0][2] <= passes[1][1]
+    assert aggs and {a.closed_at_watermark for a in aggs} == {77.0}
 
 
 def test_launch_counters_tell_replay_and_drain_apart(tmp_path):
